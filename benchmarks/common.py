@@ -17,6 +17,7 @@ import numpy as np
 # the ONE timing loop (min-of-k, warmup + block_until_ready) every benchmark
 # shares with the stage executor — canonical home is repro.timing so src-side
 # code can use it without importing benchmarks
+from repro import compile_cache
 from repro.timing import Timing, time_fn, time_interleaved  # noqa: F401
 
 RESULTS_DIR = os.path.join("experiments", "results")
@@ -41,7 +42,8 @@ def bench_args(argv=None, *, description: str | None = None,
                parser: argparse.ArgumentParser | None = None):
     """The flags every benchmark script shares: ``--quick`` (CI-sized
     episode/epoch counts) and ``--out DIR`` (JSON destination). Pass a
-    pre-built ``parser`` to stack script-specific flags on top."""
+    pre-built ``parser`` to stack script-specific flags on top. Turns on
+    the persistent compilation cache (``repro.compile_cache``)."""
     ap = parser or argparse.ArgumentParser(description=description)
     ap.add_argument(
         "--quick",
@@ -55,6 +57,7 @@ def bench_args(argv=None, *, description: str | None = None,
         help=f"write JSON results here (default {RESULTS_DIR})",
     )
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.out:
         set_results_dir(args.out)
     return args

@@ -5,13 +5,14 @@ in miniature), built entirely from ``repro.api`` specs.
 """
 import argparse
 
-from repro import api
+from repro import api, compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--workload", default="fluctuating",
                 choices=["steady_low", "fluctuating", "steady_high"])
 ap.add_argument("--episodes", type=int, default=8)
 args = ap.parse_args()
+compile_cache.enable()
 
 scenario = api.replace(api.get_scenario(args.workload), seed=42)
 

@@ -13,11 +13,12 @@ import argparse
 
 import numpy as np
 
-from repro import api
+from repro import api, compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--episodes", type=int, default=8)
 args = ap.parse_args()
+compile_cache.enable()
 
 pipe_spec = api.get_pipeline("paper-4stage")
 pipe = pipe_spec.build()

@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from repro import api
+from repro import api, compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--horizon", type=int, default=120,
@@ -30,6 +30,7 @@ ap.add_argument("--seq-len", type=int, default=32)
 ap.add_argument("--real", action="store_true",
                 help="attach live smoke-scale JAX models as stage executors")
 args = ap.parse_args()
+compile_cache.enable()
 
 exp = api.ExperimentSpec(
     pipeline=api.get_pipeline("serve3"),

@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS
 from repro.data.tokens import synthetic_lm_batches
 from repro.models import api, steps
@@ -26,6 +27,7 @@ ap.add_argument("--batch", type=int, default=8)
 ap.add_argument("--d-model", type=int, default=None,
                 help="override width (default: ~100M-param reduction)")
 args = ap.parse_args()
+compile_cache.enable()
 
 base = ARCHS[args.arch]
 # reduce to ~100M params for a CPU-trainable run, keep the family intact

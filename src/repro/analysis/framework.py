@@ -2,8 +2,8 @@
 
 The jitted pure-JAX twins (``core/vecenv.py``, ``core/runtime_vec.py``) stay
 bit-equivalent to their Python references only while every PR obeys a pile of
-implicit conventions — key hygiene, no host numerics in traced code, compat
-shims instead of raw version-sensitive ``jax.*`` APIs, JSON-safe frozen
+implicit conventions — key hygiene, no host numerics in traced code, the
+compat mesh helpers instead of raw mesh ``jax.*`` APIs, JSON-safe frozen
 specs, no CPU loop-lowering anti-patterns. This module is the machinery that
 lets ``repro.analysis.rules`` state those conventions as checkable rules:
 

@@ -7,7 +7,7 @@ rule:
 
   RPL001  PRNG key reuse (the OPD jit-warmup bug fixed in PR 2)
   RPL002  host-side numerics in jit-pure modules (twin-divergence hazard)
-  RPL003  raw version-sensitive ``jax.*`` APIs that bypass ``repro.compat``
+  RPL003  raw mesh ``jax.*`` APIs that bypass ``repro.compat``
   RPL004  spec-safety: ``*Spec`` dataclasses frozen + JSON-round-trip safe
   RPL005  CPU loop-lowering anti-patterns (the PR 5 event-loop lessons)
   RPL006  device→host syncs inside a benchmark's timed region
@@ -39,20 +39,10 @@ _TRACE_ENTRY = frozenset({
     "jax.value_and_grad", "jax.checkpoint", "jax.remat",
 })
 
-# Raw API -> the repro.compat shim that must be used instead.
+# Raw API -> the repro.compat helper that must be used instead.
 _COMPAT_SHIMS = {
-    "jax.shard_map": "repro.compat.shard_map",
-    "jax.experimental.shard_map.shard_map": "repro.compat.shard_map",
-    "jax.sharding.use_mesh": "repro.compat.use_mesh",
-    "jax.set_mesh": "repro.compat.use_mesh",
     "jax.sharding.get_abstract_mesh": "repro.compat.ambient_mesh",
-    "jax.interpreters.pxla.thread_resources": "repro.compat.ambient_mesh",
-    "jax.sharding.AbstractMesh": "repro.compat.abstract_mesh",
     "jax.make_mesh": "repro.compat.make_mesh",
-    "jax.experimental.pallas.tpu.CompilerParams":
-        "repro.compat.pallas_tpu_compiler_params",
-    "jax.experimental.pallas.tpu.TPUCompilerParams":
-        "repro.compat.pallas_tpu_compiler_params",
 }
 
 _JSON_ATOMS = frozenset({"str", "int", "float", "bool", "None"})
@@ -365,13 +355,14 @@ def _traced_functions(mod: SourceModule) -> Iterator[ast.FunctionDef]:
 
 @register
 class CompatBypass(Rule):
-    """The mesh/pallas/cost-analysis surface moves between jax releases;
-    ``repro.compat`` pins every call site to one bridging module. Raw use
-    of the version-sensitive APIs reintroduces the drift PR 1 fixed."""
+    """``repro.compat`` holds the project's mesh conventions: meshes with
+    Auto axes (``jax.make_mesh`` defaults to Explicit, which the sharding
+    rules do not expect) and an ambient-mesh query that returns None
+    outside a mesh scope. Raw use of those APIs bypasses both."""
     code = "RPL003"
     name = "compat-shim-bypass"
     severity = ERROR
-    description = "raw version-sensitive jax API bypassing repro.compat"
+    description = "raw mesh API bypassing repro.compat"
 
     def check(self, mod: SourceModule):
         if mod.path.endswith("repro/compat.py"):
@@ -388,16 +379,8 @@ class CompatBypass(Rule):
                 ref = mod.resolve(node)
                 shim = _COMPAT_SHIMS.get(ref) if ref else None
                 if shim:
-                    yield (node, f"raw {ref!r} is version-sensitive; "
-                           f"use {shim}")
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "cost_analysis"
-                    and not (mod.resolve(node.func) or "").startswith(
-                        "repro.compat")):
-                yield (node, "Compiled.cost_analysis() returns different "
-                       "shapes across jax versions; use "
-                       "repro.compat.cost_analysis(compiled)")
+                    yield (node, f"raw {ref!r} bypasses the project's mesh "
+                           f"conventions; use {shim}")
 
 
 # --------------------------------------------------------------- RPL004 --

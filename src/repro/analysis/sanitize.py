@@ -74,9 +74,9 @@ def checked(fn=None, *, errors=None):
     NaN / div-by-zero / out-of-bounds index anywhere in the episode.
 
     ``errors`` narrows the check set for functions where part of the
-    default instrumentation cannot be applied (on jax 0.4.x, checkify's
-    OOB rule fails to transform the batched ``dynamic_update_slice`` in
-    the runtime twin's vmapped event loop — those entry points keep
+    default instrumentation cannot be applied (checkify's OOB rule fails
+    to transform the batched ``dynamic_update_slice`` in the runtime
+    twin's vmapped event loop — those entry points keep
     NaN + div checks and note why inline).
 
     Works with the twins' calling convention: positional args are arrays,
@@ -93,7 +93,7 @@ def checked(fn=None, *, errors=None):
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        if not enabled() or not jax.core.trace_state_clean():
+        if not enabled() or not jax.core.trace_ctx.is_top_level():
             return fn(*args, **kwargs)
         try:
             cache_key = tuple(sorted(kwargs.items()))

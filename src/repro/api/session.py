@@ -32,13 +32,22 @@ _TRAINABLE = ("opd", "proactive")
 
 
 def build_executors(spec: ExperimentSpec):
-    """Live smoke-scale JAX models as stage executors for ``real`` runs."""
+    """Live JAX models as stage executors for ``real`` runs, one
+    ``StageServer`` per stage, at the spec's ``widths``."""
     from repro.configs import ARCHS
     from repro.serving.engine import StageServer
-    servers = [StageServer(f"stage{i}", [ARCHS[n].smoke() for n in names],
-                           seq_len=spec.seq_len, seed=i)
-               for i, names in enumerate(spec.pipeline.stages)]
-    return [s.execute for s in servers]
+    if spec.widths == "smoke":
+        def variant(name):
+            return ARCHS[name].smoke()
+    elif spec.widths == "published":
+        def variant(name):
+            return ARCHS[name].replace(dtype="bfloat16")
+    else:
+        raise ValueError(f"unknown widths {spec.widths!r} "
+                         "(one of: smoke, published)")
+    return [StageServer(f"stage{i}", [variant(n) for n in names],
+                        seq_len=spec.seq_len, seed=i)
+            for i, names in enumerate(spec.pipeline.stages)]
 
 
 class Session:
@@ -218,6 +227,8 @@ class Session:
             summary["switches"] = env.runtime.switch_count
             summary["utilization"] = env.runtime.utilization()
             summary["virtual_now"] = env.runtime.now
+            if env.executors:
+                summary["executors"] = [s.stats() for s in env.executors]
         self._report = {
             "experiment": self.spec.to_dict(),
             # params injected via with_params() are not derivable from the

@@ -274,20 +274,24 @@ class ExperimentSpec:
     """One full run. ``backend`` selects the simulator: "runtime" steps the
     event-driven ServingRuntime (measured telemetry), "analytic" steps the
     closed-form PipelineEnv (cheap, used for training). ``real`` attaches
-    live smoke-scale JAX models as stage executors (runtime backend only)."""
+    live JAX models as stage executors (runtime backend only); ``widths``
+    sizes them: "smoke" (float32 same-family variants at most 256 wide,
+    for the CPU) or "published" (each architecture's published config,
+    weights in bf16)."""
     pipeline: PipelineSpec
     scenario: ScenarioSpec
     controller: ControllerSpec
     backend: str = "runtime"     # "runtime" | "analytic"
     real: bool = False
     seq_len: int = 32
+    widths: str = "smoke"        # "smoke" | "published"
 
     def to_dict(self) -> dict:
         return {"pipeline": self.pipeline.to_dict(),
                 "scenario": self.scenario.to_dict(),
                 "controller": self.controller.to_dict(),
                 "backend": self.backend, "real": self.real,
-                "seq_len": self.seq_len}
+                "seq_len": self.seq_len, "widths": self.widths}
 
     @classmethod
     def from_dict(cls, d: dict) -> ExperimentSpec:
@@ -296,7 +300,8 @@ class ExperimentSpec:
                    controller=ControllerSpec.from_dict(d["controller"]),
                    backend=d.get("backend", "runtime"),
                    real=bool(d.get("real", False)),
-                   seq_len=int(d.get("seq_len", 32)))
+                   seq_len=int(d.get("seq_len", 32)),
+                   widths=str(d.get("widths", "smoke")))
 
 
 @dataclass(frozen=True)

@@ -21,6 +21,7 @@ measured ``latency(b)`` curves back into per-variant ``(alpha, beta)``.
 """
 from __future__ import annotations
 
+import time  # reprolint: ignore[RPL002] compile timing on the host, outside traced code
 from dataclasses import dataclass, field
 
 import jax
@@ -134,19 +135,24 @@ class StageExecutor:
 
     ``smoke=True`` (the CPU default) runs each architecture's reduced
     same-family variant (``ArchConfig.smoke``) so the sweep fits host
-    memory; the production launch flips it off on a real accelerator mesh.
-    ``cache`` may be shared between executors (e.g. one per mesh shape) so
-    a fleet-wide sweep reuses executables across device classes.
+    memory; ``smoke=False`` runs the published widths. ``n_layers`` cuts
+    every architecture to that depth with its widths unchanged (a stage
+    too deep for the mesh's memory). ``cache`` may be shared between
+    executors (e.g. one per mesh shape) so a fleet-wide sweep reuses
+    executables across device classes; executors sharing a cache must
+    agree on ``smoke``, ``n_layers`` and ``seed``.
     """
 
     def __init__(self, mesh=None, *, seq_len: int = 32, smoke: bool = True,
-                 seed: int = 0, cache: ExecutableCache | None = None):
+                 n_layers: int | None = None, seed: int = 0,
+                 cache: ExecutableCache | None = None):
         self.mesh = mesh if mesh is not None else default_mesh()
         self.seq_len = seq_len
         self.smoke = smoke
+        self.n_layers = n_layers
         self.seed = seed
         self.cache = cache if cache is not None else ExecutableCache()
-        self._params: dict = {}       # (arch, quant, backend) -> placed pytree
+        self._params: dict = {}       # (arch, quant) -> placed pytree
 
     # ----------------------------------------------------------- identity --
 
@@ -182,20 +188,29 @@ class StageExecutor:
         cfg = ARCHS[arch]
         if self.smoke:
             cfg = cfg.smoke()
-        return cfg.replace(use_flash=(backend == "flash"))
+        if self.n_layers is not None:
+            cfg = cfg.replace(n_layers=self.n_layers)
+        # weights are created in bf16 (every quant stores bf16), so they
+        # never occupy device memory at float32 size
+        return cfg.replace(use_flash=(backend == "flash"), dtype="bfloat16")
 
     def params_for(self, arch: str, quant: str = "bf16",
                    backend: str = "reference"):
         """Init-once, quantise, and place params under the mesh's sharding
-        rules (cached — param placement is batch-independent)."""
-        pkey = (arch, quant, backend)
+        rules (cached — params are batch- and backend-independent, so the
+        reference and flash steps run the same weights). One jitted program
+        creates each shard on its own device: no device ever holds the whole
+        unsharded model."""
+        pkey = (arch, quant)
         if pkey not in self._params:
             cfg = self.arch_config(arch, backend)
             init_key = jax.random.fold_in(
                 jax.random.PRNGKey(self.seed), len(self._params))
-            params = quantize_params(api.init_model(init_key, cfg), quant)
             psh = shd.param_shardings(cfg, self.mesh, kind="decode")
-            self._params[pkey] = jax.device_put(params, psh)
+            init = jax.jit(
+                lambda k: quantize_params(api.init_model(k, cfg), quant),
+                out_shardings=psh)
+            self._params[pkey] = init(init_key)
         return self._params[pkey]
 
     def _inputs(self, cfg: ArchConfig, shape: InputShape, data_key):
@@ -225,14 +240,13 @@ class StageExecutor:
         def build() -> _Entry:
             step = steps.make_serve_step(cfg, shape)
             psh = shd.param_shardings(cfg, self.mesh, kind="decode")
-            with compat.use_mesh(self.mesh):
-                t = time_fn(lambda: None, reps=1, warmup=0)  # clock warm-up
-                del t
+            with jax.set_mesh(self.mesh):
                 lowered = jax.jit(step, in_shardings=(psh, bsh, csh)).lower(
                     params, batch_in, cache_in)
-                timing = time_fn(lowered.compile, reps=1, warmup=0)
-            return _Entry(compiled=timing and lowered.compile(),
-                          compile_s=timing.best)
+                t0 = time.perf_counter()
+                compiled = lowered.compile()
+            return _Entry(compiled=compiled,
+                          compile_s=time.perf_counter() - t0)
 
         entry, was_hit = self.cache.get_or_build(key, build)
         return entry, (params, batch_in, cache_in), was_hit

@@ -1,74 +1,22 @@
-"""jax version bridging. The code targets the current mesh surface
-(``jax.shard_map``, ``jax.sharding.get_abstract_mesh``, typed ``make_mesh``);
-the baked toolchain may carry an older jax where those live elsewhere. Every
-mesh-aware call site imports from here so version drift stays in one file.
-Imports only jax — safe from any module without cycles.
+"""The mesh surface every mesh-aware call site shares. ``jax.make_mesh``
+defaults to Explicit axes, while the sharding rules here rely on Auto
+(GSPMD-propagated) axes; ``jax.sharding.get_abstract_mesh`` returns an
+empty mesh, not None, outside any mesh scope. Imports only jax — safe from
+any module without cycles.
 """
 from __future__ import annotations
 
 import jax
 
-try:                                      # jax >= 0.5
-    from jax import shard_map             # type: ignore[attr-defined]
-except ImportError:                       # older: experimental namespace
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 def ambient_mesh():
-    """The mesh currently in scope, or None.
-
-    New jax: the AbstractMesh set by ``jax.sharding.use_mesh``. Old jax: the
-    physical mesh entered via ``with mesh:`` (thread-resources env).
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-        return mesh if mesh is not None and mesh.axis_names else None
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
-def abstract_mesh(shape, axes):
-    """AbstractMesh across constructor generations: new jax takes
-    (axis_sizes, axis_names); old jax takes ((name, size), ...) pairs."""
-    AbstractMesh = jax.sharding.AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape, strict=True)))
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` /
-    ``jax.sharding.use_mesh`` where present, the mesh's own context manager
-    (``with mesh:``) on older jax."""
-    setter = getattr(jax, "set_mesh", None) \
-        or getattr(jax.sharding, "use_mesh", None)
-    return setter(mesh) if setter is not None else mesh
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict: older jax returns a per-device
-    list of dicts, newer jax the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params: ``CompilerParams`` on new jax,
-    ``TPUCompilerParams`` on older releases."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """The mesh set by ``jax.set_mesh`` currently in scope, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def make_mesh(shape, axes):
-    """Typed mesh when AxisType exists (auto sharding axes), plain otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """A mesh over local devices whose axes are all Auto (GSPMD propagates
+    shardings)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -648,7 +648,7 @@ def rollout(params, tables: PipelineTables, ep: EpisodeArrivals,
 
 
 # NaN + div only: checkify's OOB rule can't transform the batched
-# dynamic_update_slice in the vmapped event loop on jax 0.4.x
+# dynamic_update_slice in the vmapped event loop
 @sanitize.checked(errors=sanitize.NAN_DIV_ERRORS)
 @partial(jax.jit, static_argnames=("n_steps", "weights", "max_wait",
                                    "greedy"))

@@ -9,8 +9,11 @@ size), so the kernel is organised to stream K/V through VMEM exactly once:
   GQA group is folded into the matmul M dimension so the MXU tile is
   [group, D] x [D, bk] instead of a degenerate [1, D] GEMV).
 
-Valid-length masking supports both contiguous caches (pos < n_valid) and
-ring-buffer window caches (mask supplied per slot by the wrapper).
+Validity is a per-sequence length: slots [0, length) hold keys, for
+contiguous caches and ring-buffer window caches alike (a ring's filled
+slots are its first min(pos + 1, C)). The lengths ride in SMEM as a
+scalar-prefetch operand, so no mask tile has to meet the (8, 128) VMEM
+block tiling, and kv blocks wholly past a sequence's length are skipped.
 """
 from __future__ import annotations
 
@@ -21,14 +24,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
-def _dec_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                scale: float, n_k: int):
+def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                scale: float, bk: int, n_k: int):
+    bh = pl.program_id(0)
     ik = pl.program_id(2)
+    length = len_ref[bh]
 
     @pl.when(ik == 0)
     def _init():
@@ -36,22 +39,24 @@ def _dec_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # [g, D]
-    k = k_ref[0].astype(jnp.float32)                  # [bk, D]
-    v = v_ref[0].astype(jnp.float32)                  # [bk, D]
-    valid = mask_ref[0]                               # [bk] bool
-    s = q @ k.T                                       # [g, bk]
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    @pl.when(ik * bk < length)
+    def _compute():
+        q = q_ref[0].astype(jnp.float32) * scale          # [g, D]
+        k = k_ref[0].astype(jnp.float32)                  # [bk, D]
+        v = v_ref[0].astype(jnp.float32)                  # [bk, D]
+        s = q @ k.T                                       # [g, bk]
+        cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < length, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    l_prev = l_scr[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + p @ v
-    m_scr[...] = m_new
+        m_prev = m_scr[...]
+        l_prev = l_scr[...]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + p @ v
+        m_scr[...] = m_new
 
     @pl.when(ik == n_k - 1)
     def _finalize():
@@ -61,8 +66,10 @@ def _dec_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
-def decode_attention(q, k, v, valid_mask, *, bk: int = 512, interpret: bool = True):
-    """q [B, 1, H, D]; k, v [B, C, Hkv, D]; valid_mask [B, C] -> [B, 1, H, D]."""
+def decode_attention(q, k, v, lengths, *, interpret: bool, bk: int = 512):
+    """q [B, 1, H, D]; k, v [B, C, Hkv, D]; lengths [B] (valid slots
+    [0, length)) -> [B, 1, H, D]. ``interpret`` runs the kernel body in
+    the Pallas interpreter (CPU) instead of compiling it with Mosaic."""
     B, _, H, D = q.shape
     C = k.shape[1]
     Hkv = k.shape[2]
@@ -76,36 +83,35 @@ def decode_attention(q, k, v, valid_mask, *, bk: int = 512, interpret: bool = Tr
     qf = q[:, 0].reshape(B, Hkv, g, D).reshape(B * Hkv, g, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, C, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, C, D)
-    maskf = jnp.repeat(valid_mask, Hkv, axis=0)       # [B*Hkv, C]
+    lens = jnp.repeat(lengths.astype(jnp.int32), Hkv)  # [B*Hkv]
 
-    def q_map(bh, _h, ik):
+    def q_map(bh, _h, ik, _lens):
         return (bh, 0, 0)
 
-    def kv_map(bh, _h, ik):
+    def kv_map(bh, _h, ik, _lens):
         return (bh, ik, 0)
 
-    def mask_map(bh, _h, ik):
-        return (bh, ik)
-
-    kernel = functools.partial(_dec_kernel, scale=scale, n_k=n_k)
+    kernel = functools.partial(_dec_kernel, scale=scale, bk=bk, n_k=n_k)
     out = pl.pallas_call(
         kernel,
-        grid=(B * Hkv, 1, n_k),
-        in_specs=[
-            pl.BlockSpec((1, g, D), q_map),
-            pl.BlockSpec((1, bk, D), kv_map),
-            pl.BlockSpec((1, bk, D), kv_map),
-            pl.BlockSpec((1, bk), mask_map),
-        ],
-        out_specs=pl.BlockSpec((1, g, D), q_map),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * Hkv, 1, n_k),
+            in_specs=[
+                pl.BlockSpec((1, g, D), q_map),
+                pl.BlockSpec((1, bk, D), kv_map),
+                pl.BlockSpec((1, bk, D), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, g, D), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
-        ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, maskf)
+    )(lens, qf, kf, vf)
     return out.reshape(B, Hkv * g, D)[:, None].reshape(B, 1, H, D)

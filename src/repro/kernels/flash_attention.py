@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -83,9 +81,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
-    """q [B, S, H, D]; k, v [B, S, Hkv, D] -> [B, S, H, D]."""
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    window: int | None = None, bq: int = 128, bk: int = 128):
+    """q [B, S, H, D]; k, v [B, S, Hkv, D] -> [B, S, H, D]. ``interpret``
+    runs the kernel body in the Pallas interpreter (CPU) instead of
+    compiling it with Mosaic."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     g = H // Hkv
@@ -123,7 +123,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

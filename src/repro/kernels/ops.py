@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this dev container) kernels run in interpret mode — the kernel body
-executes in Python with real dataflow, validating correctness against
-ref.py; on TPU the same pallas_call lowers to Mosaic.
+The one place that decides how the kernels run: on a CPU backend (tests)
+the kernel body executes in the Pallas interpreter with real dataflow,
+validated against ref.py; on any other backend the same pallas_call is
+compiled by Mosaic.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
                                bq=bq, bk=bk, interpret=_interpret())
 
 
-def decode_attention(q, k, v, valid_mask, *, bk: int = 512):
-    """q [B, 1, H, D]; k, v [B, C, Hkv, D]; valid_mask [B, C] -> [B, 1, H, D]."""
-    return _da.decode_attention(q, k, v, valid_mask, bk=bk,
+def decode_attention(q, k, v, lengths, *, bk: int = 512):
+    """q [B, 1, H, D]; k, v [B, C, Hkv, D]; lengths [B] -> [B, 1, H, D]."""
+    return _da.decode_attention(q, k, v, lengths, bk=bk,
                                 interpret=_interpret())

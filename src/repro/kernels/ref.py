@@ -24,14 +24,16 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int | None = No
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
-def decode_attention_ref(q, k, v, valid_mask):
-    """q [B, 1, H, D]; k, v [B, C, Hkv, D]; valid_mask [B, C] -> [B, 1, H, D]."""
+def decode_attention_ref(q, k, v, lengths):
+    """q [B, 1, H, D]; k, v [B, C, Hkv, D]; lengths [B] -> [B, 1, H, D]."""
     B, _, H, D = q.shape
+    C = k.shape[1]
     Hkv = k.shape[2]
     g = H // Hkv
     qg = q.reshape(B, 1, Hkv, g, D).astype(jnp.float32)
     logits = jnp.einsum("bshgd,bthd->bhgst", qg, k.astype(jnp.float32)) / (D ** 0.5)
-    mask = valid_mask[:, None, None, None, :]
+    valid = jnp.arange(C)[None, :] < lengths[:, None]           # [B, C]
+    mask = valid[:, None, None, None, :]
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgst,bthd->bshgd", probs, v.astype(jnp.float32))

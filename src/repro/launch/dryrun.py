@@ -19,7 +19,6 @@ import time
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import ARCHS
 from repro.distributed import sharding as shd
 from repro.launch import hlo_cost
@@ -96,7 +95,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     args, in_sh, step, donate = build_inputs(cfg, shape, mesh,
                                              multi_pod=multi_pod)
     t0 = time.time()
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=in_sh,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
@@ -105,7 +104,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     # trip-count-aware costs (XLA's cost_analysis counts scan bodies once)
     hc = hlo_cost.analyze(hlo)

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS
 from repro.models import api
 
@@ -135,6 +136,7 @@ def main():
     ap.add_argument("--horizon", type=int, default=120)
     ap.add_argument("--rate", type=float, default=25.0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.fleet:
         return run_fleet(args)

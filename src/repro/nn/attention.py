@@ -162,11 +162,11 @@ def attention_decode(params, x, cache, *, n_heads: int, n_kv: int, head_dim: int
     new_v = cache["v"].at[bidx, slot].set(v[:, 0].astype(cache["v"].dtype))  # reprolint: ignore[RPL005] canonical decode-path KV slot write, not vmapped over the cache
     # valid slots: contiguous -> [0, pos]; ring -> min(pos+1, C) most recent
     n_valid = jnp.minimum(pos + 1, C)                    # [B]
-    mask = jnp.arange(C)[None, :] < n_valid[:, None]     # [B, C]
     if use_flash:
         from repro.kernels import ops as kops
-        out = kops.decode_attention(q, new_k, new_v, mask)
+        out = kops.decode_attention(q, new_k, new_v, n_valid)
     else:
+        mask = jnp.arange(C)[None, :] < n_valid[:, None]     # [B, C]
         out = _sdpa(q, new_k, new_v, mask[:, None, None, None, :])
     out = out.reshape(B, 1, n_heads * head_dim)
     new_cache = {"k": new_k, "v": new_v, "pos": pos + 1}

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import ambient_mesh, shard_map
+from repro.compat import ambient_mesh
 from repro.nn.linear import init_linear
 
 
@@ -149,7 +149,7 @@ def moe(params, x, *, top_k: int, capacity_factor: float = 1.25,
             y = y.at[jnp.broadcast_to(bidx, tok_l.shape), tok_l].add(ye)
             return jax.lax.psum(y, ("model", "data"))
 
-        y = shard_map(
+        y = jax.shard_map(
             body2d, mesh=mesh,
             in_specs=(P(None, None, None), P(None, "model", None),
                       P(None, "model", None), P("model", None, "data"),
@@ -168,7 +168,7 @@ def moe(params, x, *, top_k: int, capacity_factor: float = 1.25,
             y = _dispatch_compute_combine(x_l, gsel_l, tok_l, wg_l, wu_l, wd_l)
             return jax.lax.psum(y, "model")
 
-        y = shard_map(
+        y = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, "model", None),
                       P(dp, "model", None), P("model", None, None),

@@ -12,6 +12,7 @@ production, charged by the simulator).
 """
 from __future__ import annotations
 
+import time
 
 import jax
 import jax.numpy as jnp
@@ -24,35 +25,58 @@ from repro.serving.batcher import Batcher, Request
 
 
 class StageServer:
+    """A stage's variants behind one callable ``server(z, tokens)``.
+
+    A variant's weights are created, in its config's dtype, the first time
+    a batch is dispatched to it — a stage holds device memory only for the
+    variants the controller actually runs. Each distinct (variant, batch
+    size) is compiled once, ahead of time; ``stats()`` reports what ran and
+    what compiling cost.
+    """
+
     def __init__(self, name: str, variants: list[ArchConfig], *,
                  seq_len: int = 32, batch_size: int = 4, replicas: int = 1,
                  seed: int = 0):
         self.name = name
         self.variants = variants
         self.seq_len = seq_len
-        self.params = [api.init_model(jax.random.PRNGKey(seed + i), cfg)
-                       for i, cfg in enumerate(variants)]
+        self.seed = seed
         self.z = 0
         self.replicas = replicas
         self.batcher = Batcher(batch_size, seq_len)
-        self._fwd_cache: dict[int, callable] = {}
+        self.params: dict[int, object] = {}       # z -> weights, on demand
+        self._compiled: dict[tuple[int, int], object] = {}  # (z, B) -> exe
+        self.compile_s = 0.0
+        self.batches: dict[int, int] = {}         # z -> batches executed
         self.served = 0
 
     @property
     def cfg(self) -> ArchConfig:
         return self.variants[self.z]
 
-    def _fwd(self, z: int):
-        if z not in self._fwd_cache:
+    def weights(self, z: int):
+        """Variant ``z``'s weights, created by one jitted program on first
+        use (a bf16 variant is drawn in bf16 and never exists in float32)."""
+        if z not in self.params:
+            cfg = self.variants[z]
+            self.params[z] = jax.jit(lambda k: api.init_model(k, cfg))(
+                jax.random.PRNGKey(self.seed + z))
+        return self.params[z]
+
+    def _executable(self, z: int, batch: dict):
+        key = (z, batch["tokens"].shape[0])
+        if key not in self._compiled:
             cfg = self.variants[z]
 
-            @jax.jit
             def fwd(params, batch):
                 logits, _ = api.forward(params, batch, cfg)
                 return jnp.argmax(logits, axis=-1)
 
-            self._fwd_cache[z] = fwd
-        return self._fwd_cache[z]
+            t0 = time.perf_counter()
+            self._compiled[key] = jax.jit(fwd).lower(
+                self.weights(z), batch).compile()
+            self.compile_s += time.perf_counter() - t0
+        return self._compiled[key]
 
     def configure(self, *, z: int | None = None, batch_size: int | None = None,
                   replicas: int | None = None):
@@ -66,29 +90,45 @@ class StageServer:
     def _make_batch(self, tokens: np.ndarray, cfg: ArchConfig) -> dict:
         batch = {"tokens": jnp.asarray(tokens % cfg.vocab)}
         B = tokens.shape[0]
+        dt = cfg.param_dtype
         if cfg.family == "vlm":
             key = jax.random.PRNGKey(0)
             batch["vision_embeds"] = jax.random.normal(
-                key, (B, cfg.n_patches, cfg.d_model), jnp.float32) * 0.02
+                key, (B, cfg.n_patches, cfg.d_model), dt) * 0.02
         if cfg.family == "audio":
             key = jax.random.PRNGKey(1)
             batch["enc_states"] = jax.random.normal(
-                key, (B, cfg.enc_len, cfg.d_model), jnp.float32) * 0.02
+                key, (B, cfg.enc_len, cfg.d_model), dt) * 0.02
         return batch
 
     def execute(self, z: int, tokens: np.ndarray) -> np.ndarray:
         """Run variant ``z`` on tokens [B, S] -> output tokens [B, S].
 
         This is the real-JAX execution hook: the event-driven runtime
-        (serving.runtime) can attach it as a stage ``executor`` so virtual
-        time is charged analytically while outputs flow through live models.
-        Batches arrive at their actual size (no tail padding) — jit retraces
-        per distinct (z, B) shape and then reuses the compiled kernel.
+        (serving.runtime) can attach the server as a stage ``executor`` so
+        virtual time is charged analytically while outputs flow through
+        live models. Batches arrive at their actual size (no tail padding)
+        — each distinct (z, B) compiles once and is then reused.
         """
         z = int(z) % len(self.variants)
-        fwd = self._fwd(z)
-        return np.asarray(fwd(self.params[z],
-                              self._make_batch(tokens, self.variants[z])))
+        batch = self._make_batch(tokens, self.variants[z])
+        out = self._executable(z, batch)(self.weights(z), batch)
+        self.batches[z] = self.batches.get(z, 0) + 1
+        return np.asarray(out)
+
+    __call__ = execute
+
+    def stats(self) -> dict:
+        """JSON-safe record of what this stage executed: per variant run,
+        its dtype and batch count; the variants holding weights; distinct
+        shapes compiled and the seconds spent compiling them."""
+        return {"stage": self.name,
+                "executed": {self.variants[z].name:
+                             {"batches": n, "dtype": self.variants[z].dtype}
+                             for z, n in sorted(self.batches.items())},
+                "loaded": [self.variants[z].name for z in sorted(self.params)],
+                "compiled_shapes": len(self._compiled),
+                "compile_s": self.compile_s}
 
     def serve_pending(self) -> list[Request]:
         """Drain the queue; returns completed requests with stage output."""

@@ -154,20 +154,22 @@ class TestCompatBypass:
         found = [f for f in analyze_source(src, "f.py") if f.rule == "RPL003"]
         assert found and "repro.compat.make_mesh" in found[0].message
 
-    def test_catches_shard_map_import(self):
-        src = "from jax.experimental.shard_map import shard_map\n"
+    def test_catches_make_mesh_import(self):
+        src = "from jax import make_mesh\n"
         assert "RPL003" in codes(src)
 
-    def test_catches_raw_cost_analysis(self):
-        src = "stats = compiled.cost_analysis()\n"
+    def test_catches_raw_get_abstract_mesh(self):
+        src = "import jax\nmesh = jax.sharding.get_abstract_mesh()\n"
         found = [f for f in analyze_source(src, "f.py") if f.rule == "RPL003"]
-        assert found and "repro.compat.cost_analysis" in found[0].message
+        assert found and "repro.compat.ambient_mesh" in found[0].message
 
     def test_clean_compat_usage(self):
         src = (
-            "from repro.compat import cost_analysis, make_mesh, shard_map\n"
+            "import jax\n"
+            "from repro.compat import ambient_mesh, make_mesh\n"
             'mesh = make_mesh((2, 2), ("a", "b"))\n'
-            "stats = cost_analysis(compiled)\n"
+            "with jax.set_mesh(mesh):\n"
+            "    assert ambient_mesh() is not None\n"
         )
         assert "RPL003" not in codes(src)
 

@@ -10,7 +10,6 @@ import textwrap
 import jax
 import pytest
 
-from repro.compat import abstract_mesh
 from repro.configs import ARCHS
 from repro.distributed import sharding as shd
 
@@ -21,7 +20,7 @@ class TestShardingSpecs:
         """Every param leaf's spec must divide its dims on the 16x16 mesh —
         checked abstractly (no devices needed)."""
         cfg = ARCHS[name]
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
         for kind in ("train", "decode"):
             psh = shd.param_shardings(cfg, mesh, kind=kind)
             shapes = jax.eval_shape(
@@ -45,7 +44,7 @@ class TestShardingSpecs:
 
     def test_zero1_adds_data_axis_somewhere(self):
         cfg = ARCHS["llama3.2-1b"]
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
         osh = shd.opt_shardings(cfg, mesh)
         specs = [s.spec for s in jax.tree.leaves(osh)]
         assert any(
@@ -58,7 +57,7 @@ MOE_EP_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro import nn
-    from repro.compat import make_mesh, use_mesh
+    from repro.compat import make_mesh
 
     key = jax.random.PRNGKey(0)
     p = nn.init_moe(key, 32, 64, 16)          # E=16 -> padded stays 16
@@ -67,7 +66,7 @@ MOE_EP_SCRIPT = textwrap.dedent("""
     y_local, aux_local = nn.moe(p, x, top_k=2)            # no mesh
 
     mesh = make_mesh((2, 4), ("data", "model"))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep, aux_ep = jax.jit(lambda p_, x_: nn.moe(p_, x_, top_k=2))(p, x)
 
     err = float(jnp.abs(y_local - y_ep).max())
@@ -82,7 +81,7 @@ DRYRUN_SMOKE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import cost_analysis, make_mesh, use_mesh
+    from repro.compat import make_mesh
     from repro.configs import ARCHS
     from repro.distributed import sharding as shd
     from repro.models import api, steps
@@ -103,11 +102,11 @@ DRYRUN_SMOKE_SCRIPT = textwrap.dedent("""
     opt_shape = jax.eval_shape(adamw_init, params_shape)
     osh = {"m": zsh, "v": zsh, "step": NamedSharding(mesh, P())}
     step = steps.make_train_step(cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=(psh, osh, bsh),
                            donate_argnums=(0, 1)).lower(
             params_shape, opt_shape, bs).compile()
-    print("compiled OK", cost_analysis(compiled).get("flops", 0) > 0)
+    print("compiled OK", compiled.cost_analysis().get("flops", 0) > 0)
 """)
 
 

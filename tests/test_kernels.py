@@ -69,9 +69,9 @@ def test_decode_attention(B, H, Hkv, D, C, nv, dtype, tol):
     q = rand((B, 1, H, D), dtype)
     k = rand((B, C, Hkv, D), dtype)
     v = rand((B, C, Hkv, D), dtype)
-    mask = jnp.arange(C)[None, :] < jnp.full((B, 1), nv)
-    out = ops.decode_attention(q, k, v, mask)
-    want = ref.decode_attention_ref(q, k, v, mask)
+    lengths = jnp.full((B,), nv)
+    out = ops.decode_attention(q, k, v, lengths)
+    want = ref.decode_attention_ref(q, k, v, lengths)
     assert out.shape == want.shape
     assert jnp.abs(out.astype(jnp.float32) - want.astype(jnp.float32)).max() < tol
 
@@ -82,10 +82,9 @@ def test_decode_attention_ragged_batch():
     q = rand((B, 1, H, D), jnp.float32)
     k = rand((B, C, Hkv, D), jnp.float32)
     v = rand((B, C, Hkv, D), jnp.float32)
-    nv = jnp.array([[37], [512], [256]])
-    mask = jnp.arange(C)[None, :] < nv
-    out = ops.decode_attention(q, k, v, mask)
-    want = ref.decode_attention_ref(q, k, v, mask)
+    lengths = jnp.array([37, 512, 256])
+    out = ops.decode_attention(q, k, v, lengths)
+    want = ref.decode_attention_ref(q, k, v, lengths)
     assert jnp.abs(out - want).max() < 2e-3
 
 
