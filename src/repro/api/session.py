@@ -17,6 +17,7 @@ import json
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import sanitize
 from repro.cluster.env import PipelineEnv, RuntimeEnv
@@ -209,10 +210,12 @@ class Session:
         rewards, configs, decide_walls = [], [], []
         wall0 = time.perf_counter()
         done = False
-        with self._sanitize_scope():
+        with self._sanitize_scope(), TraceAnnotation("session.serve"):
             while not done:
                 t0 = time.perf_counter()
-                cfg = decide(controller, env)
+                with TraceAnnotation("controller.decide",
+                                     interval=len(decide_walls)):
+                    cfg = decide(controller, env)
                 decide_walls.append(time.perf_counter() - t0)
                 _, r, done, info = env.step(cfg)
                 rewards.append(float(r))

@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.mdp import Config
 from repro.models import api
@@ -72,10 +73,11 @@ class StageServer:
                 logits, _ = api.forward(params, batch, cfg)
                 return jnp.argmax(logits, axis=-1)
 
-            t0 = time.perf_counter()
-            self._compiled[key] = jax.jit(fwd).lower(
-                self.weights(z), batch).compile()
-            self.compile_s += time.perf_counter() - t0
+            with TraceAnnotation("stage.compile", z=z, batch=key[1]):
+                t0 = time.perf_counter()
+                self._compiled[key] = jax.jit(fwd).lower(
+                    self.weights(z), batch).compile()
+                self.compile_s += time.perf_counter() - t0
         return self._compiled[key]
 
     def configure(self, *, z: int | None = None, batch_size: int | None = None,
@@ -111,10 +113,14 @@ class StageServer:
         — each distinct (z, B) compiles once and is then reused.
         """
         z = int(z) % len(self.variants)
-        batch = self._make_batch(tokens, self.variants[z])
-        out = self._executable(z, batch)(self.weights(z), batch)
+        with TraceAnnotation("stage.prepare", batch=tokens.shape[0]):
+            batch = self._make_batch(tokens, self.variants[z])
+        with TraceAnnotation("stage.dispatch"):
+            # returns before the device ends the forward
+            out = self._executable(z, batch)(self.weights(z), batch)
         self.batches[z] = self.batches.get(z, 0) + 1
-        return np.asarray(out)
+        with TraceAnnotation("stage.wait"):
+            return np.asarray(out)
 
     __call__ = execute
 

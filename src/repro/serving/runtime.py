@@ -28,8 +28,10 @@ from __future__ import annotations
 
 import heapq
 import itertools
+import math
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.mdp import Config, Pipeline, Task, placement_for
 from repro.serving.batcher import ContinuousBatcher, Request, stack_tokens
@@ -70,6 +72,10 @@ class EventLoop:
 
     def run_until(self, t_end: float):
         """Process all events with time <= t_end; clock lands on t_end."""
+        with TraceAnnotation("runtime.advance", t_end=t_end):
+            self._advance(t_end)
+
+    def _advance(self, t_end: float):
         while self._heap and self._heap[0][0] <= t_end + 1e-12:
             t, _, kind, payload, owner = heapq.heappop(self._heap)
             self.now = max(self.now, t)
@@ -79,8 +85,9 @@ class EventLoop:
 
     def drain(self):
         """Run the loop dry — every admitted request completes."""
-        while self._heap:
-            self.run_until(self._heap[0][0])
+        with TraceAnnotation("runtime.advance", t_end=math.inf):
+            while self._heap:
+                self._advance(self._heap[0][0])
 
 
 class RuntimeStage:
@@ -379,8 +386,13 @@ class ServingRuntime:
         stage.release_replica(replica)
         stage.served += len(reqs)
         if stage.executor is not None:
-            out = np.asarray(stage.executor(
-                z, stack_tokens(reqs, stage.seq_len)))
+            # joining the ids costs about a microsecond; only a trace reads them
+            rids = (";".join(str(r.rid) for r in reqs)
+                    if TraceAnnotation.is_enabled() else "")
+            with TraceAnnotation("stage.call", stage=i, z=z, batch=len(reqs),
+                                 rids=rids):
+                out = np.asarray(stage.executor(
+                    z, stack_tokens(reqs, stage.seq_len)))
             for k, req in enumerate(reqs):
                 req.stage_outputs.append(out[k])
                 req.result = out[k]
