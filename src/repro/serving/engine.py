@@ -31,8 +31,12 @@ class StageServer:
     A variant's weights are created, in its config's dtype, the first time
     a batch is dispatched to it — a stage holds device memory only for the
     variants the controller actually runs. Each distinct (variant, batch
-    size) is compiled once, ahead of time; ``stats()`` reports what ran and
-    what compiling cost.
+    size) is compiled once, ahead of time, and ``_compiled[(z, B)]`` holds
+    that executable together with the family's stub inputs (``enc_states``
+    for ``audio``, ``vision_embeds`` for ``vlm``, none for a decoder):
+    drawn on the first call of ``(z, B)`` and fed to every later one, which
+    then runs no device program before the forward. Clearing ``_compiled``
+    releases both. ``stats()`` reports what ran and what compiling cost.
     """
 
     def __init__(self, name: str, variants: list[ArchConfig], *,
@@ -46,8 +50,10 @@ class StageServer:
         self.replicas = replicas
         self.batcher = Batcher(batch_size, seq_len)
         self.params: dict[int, object] = {}       # z -> weights, on demand
-        self._compiled: dict[tuple[int, int], object] = {}  # (z, B) -> exe
+        # (z, B) -> (executable, stub inputs)
+        self._compiled: dict[tuple[int, int], tuple[object, dict]] = {}
         self.compile_s = 0.0
+        self.stub_inputs = {"drawn": 0, "reused": 0}  # stage calls, by path
         self.batches: dict[int, int] = {}         # z -> batches executed
         self.served = 0
 
@@ -65,6 +71,8 @@ class StageServer:
         return self.params[z]
 
     def _executable(self, z: int, batch: dict):
+        """(z, B)'s compiled forward, compiled on a miss and kept with the
+        batch's stub inputs."""
         key = (z, batch["tokens"].shape[0])
         if key not in self._compiled:
             cfg = self.variants[z]
@@ -75,10 +83,11 @@ class StageServer:
 
             with TraceAnnotation("stage.compile", z=z, batch=key[1]):
                 t0 = time.perf_counter()
-                self._compiled[key] = jax.jit(fwd).lower(
-                    self.weights(z), batch).compile()
+                exe = jax.jit(fwd).lower(self.weights(z), batch).compile()
                 self.compile_s += time.perf_counter() - t0
-        return self._compiled[key]
+            stubs = {k: v for k, v in batch.items() if k != "tokens"}
+            self._compiled[key] = (exe, stubs)
+        return self._compiled[key][0]
 
     def configure(self, *, z: int | None = None, batch_size: int | None = None,
                   replicas: int | None = None):
@@ -89,8 +98,16 @@ class StageServer:
         if replicas is not None:
             self.replicas = int(replicas)
 
+    @staticmethod
+    def _host_tokens(tokens: np.ndarray, cfg: ArchConfig) -> np.ndarray:
+        """Token ids on the host as int32: the compiled call transfers
+        them, so no device program converts them."""
+        return np.asarray(tokens % cfg.vocab, dtype=np.int32)
+
     def _make_batch(self, tokens: np.ndarray, cfg: ArchConfig) -> dict:
-        batch = {"tokens": jnp.asarray(tokens % cfg.vocab)}
+        """The forward's inputs: the tokens and the family's stub inputs,
+        drawn op by op from a fixed key."""
+        batch = {"tokens": self._host_tokens(tokens, cfg)}
         B = tokens.shape[0]
         dt = cfg.param_dtype
         if cfg.family == "vlm":
@@ -113,8 +130,17 @@ class StageServer:
         — each distinct (z, B) compiles once and is then reused.
         """
         z = int(z) % len(self.variants)
-        with TraceAnnotation("stage.prepare", batch=tokens.shape[0]):
-            batch = self._make_batch(tokens, self.variants[z])
+        cfg = self.variants[z]
+        kept = self._compiled.get((z, tokens.shape[0]))
+        reused = kept is not None and bool(kept[1])
+        with TraceAnnotation("stage.prepare", batch=tokens.shape[0],
+                             reused=int(reused)):
+            if kept is None:
+                batch = self._make_batch(tokens, cfg)
+            else:
+                batch = {"tokens": self._host_tokens(tokens, cfg), **kept[1]}
+        if len(batch) > 1:                   # the family has stub inputs
+            self.stub_inputs["reused" if reused else "drawn"] += 1
         with TraceAnnotation("stage.dispatch"):
             # returns before the device ends the forward
             out = self._executable(z, batch)(self.weights(z), batch)
@@ -127,14 +153,16 @@ class StageServer:
     def stats(self) -> dict:
         """JSON-safe record of what this stage executed: per variant run,
         its dtype and batch count; the variants holding weights; distinct
-        shapes compiled and the seconds spent compiling them."""
+        shapes compiled and the seconds spent compiling them; and the stage
+        calls that drew their stub inputs or reused kept ones."""
         return {"stage": self.name,
                 "executed": {self.variants[z].name:
                              {"batches": n, "dtype": self.variants[z].dtype}
                              for z, n in sorted(self.batches.items())},
                 "loaded": [self.variants[z].name for z in sorted(self.params)],
                 "compiled_shapes": len(self._compiled),
-                "compile_s": self.compile_s}
+                "compile_s": self.compile_s,
+                "stub_inputs": dict(self.stub_inputs)}
 
     def serve_pending(self) -> list[Request]:
         """Drain the queue; returns completed requests with stage output."""

@@ -1,10 +1,16 @@
 """Serving engine integration: pipeline chaining, batching, reconfiguration."""
+import gc
+import weakref
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS
 from repro.core.mdp import Config
 from repro.data import synthetic_lm_batches, synthetic_requests
+from repro.models import api as models
 from repro.serving import PipelineServer, StageServer
 
 
@@ -77,3 +83,99 @@ def test_data_pipeline_learnable_and_deterministic():
     # structured: token distribution far from uniform
     _, counts = np.unique(b1["tokens"], return_counts=True)
     assert counts.max() > 3 * counts.mean()
+
+
+# ----------------------------------------------- stub inputs and host tokens --
+
+def _fresh_frames(cfg, B):
+    """Whisper's stub frames drawn op by op from their fixed key."""
+    return jax.random.normal(jax.random.PRNGKey(1),
+                             (B, cfg.enc_len, cfg.d_model), cfg.param_dtype) * 0.02
+
+
+def _served(srv, batch):
+    """The served tokens of a plain jitted forward over ``batch``."""
+    logits, _ = jax.jit(lambda p, b: models.forward(p, b, srv.cfg))(srv.weights(0), batch)
+    return np.asarray(jnp.argmax(logits, axis=-1))
+
+
+@pytest.fixture
+def whisper():
+    return StageServer("asr", [ARCHS["whisper-small"].smoke()], seq_len=16, seed=3)
+
+
+def _tokens(B, seed=0):
+    return np.random.default_rng(seed).integers(0, 1000, (B, 16)).astype(np.int32)
+
+
+def _feeds(srv, key):
+    """Wraps (z, B)'s kept executable so each batch it is fed is logged."""
+    exe, stubs = srv._compiled[key]
+    fed = []
+    srv._compiled[key] = (lambda params, batch: (fed.append(batch), exe(params, batch))[1],
+                          stubs)
+    return fed
+
+
+@pytest.mark.parametrize("B", [1, 3])
+def test_kept_frames_are_bit_identical_to_a_fresh_draw(whisper, B):
+    first = whisper.execute(0, _tokens(B))
+    fed = _feeds(whisper, (0, B))
+    again = whisper.execute(0, _tokens(B))
+    (batch,) = fed
+    fresh = np.asarray(_fresh_frames(whisper.cfg, B))
+    assert batch["enc_states"].dtype == fresh.dtype
+    assert np.array_equal(np.asarray(batch["enc_states"]).view(np.uint16),
+                          fresh.view(np.uint16))
+    np.testing.assert_array_equal(first, again)
+
+
+@pytest.mark.parametrize("B", [1, 3])
+def test_execute_equals_a_forward_over_make_batch(whisper, B):
+    toks = _tokens(B, seed=B)
+    whisper.execute(0, toks)                       # the miss: draws and compiles
+    served = whisper.execute(0, toks)              # the hit: reuses the frames
+    np.testing.assert_array_equal(
+        served, _served(whisper, whisper._make_batch(toks, whisper.cfg)))
+
+
+@pytest.mark.parametrize("B", [1, 3])
+def test_a_second_call_draws_nothing(whisper, B, monkeypatch):
+    normal = jax.random.normal
+    draws = []
+
+    def counted(*args, **kwargs):
+        draws.append(args)
+        return normal(*args, **kwargs)
+
+    whisper.weights(0)                   # drawn with normal too, before the count
+    monkeypatch.setattr(jax.random, "normal", counted)
+    whisper.execute(0, _tokens(B))
+    assert len(draws) == 1
+    whisper.execute(0, _tokens(B, seed=1))
+    assert len(draws) == 1
+    assert whisper.stats()["stub_inputs"] == {"drawn": 1, "reused": 1}
+
+
+@pytest.mark.parametrize("B", [1, 3])
+def test_clearing_the_executables_releases_the_stub_inputs(whisper, B):
+    whisper.execute(0, _tokens(B))
+    (frames,) = whisper._compiled[(0, B)][1].values()
+    held = weakref.ref(frames)
+    del frames
+    whisper.params.clear()
+    whisper._compiled.clear()
+    gc.collect()
+    assert held() is None
+
+
+def test_a_decoder_stage_keeps_no_stub_inputs_and_serves_as_before():
+    srv = StageServer("code", [ARCHS["starcoder2-3b"].smoke()], seq_len=16, seed=4)
+    toks = _tokens(3, seed=7)
+    outs = [srv.execute(0, toks) for _ in range(2)]
+    assert srv._compiled[(0, 3)][1] == {}
+    assert srv.stats()["stub_inputs"] == {"drawn": 0, "reused": 0}
+    # the tokens as every call uploaded them before: converted on the device
+    before = _served(srv, {"tokens": jnp.asarray(toks % srv.cfg.vocab)})
+    for out in outs:
+        np.testing.assert_array_equal(out, before)

@@ -177,6 +177,19 @@ def test_a_compile_only_on_a_new_shape_inside_its_dispatch(served):
     assert {z for _, z, _ in seen} == {0, 1}
 
 
+def test_prepare_reuses_stub_inputs_on_a_repeated_shape(served):
+    _, env, log, spans, _ = served
+    prepares = spans["stage.prepare"]
+    seen = set()
+    for prep, (stage, z, batch, _) in zip(prepares, log, strict=True):
+        family = env.executors[stage].server.variants[z].family
+        repeat = (stage, z, batch) in seen
+        seen.add((stage, z, batch))
+        assert prep.stats["reused"] == int(repeat and family in ("audio", "vlm"))
+    reused = sum(e.stats()["stub_inputs"]["reused"] for e in env.executors)
+    assert reused == sum(p.stats["reused"] for p in prepares) > 0
+
+
 def test_one_decision_per_interval_inside_the_serve_span(served):
     report, _, _, spans, _ = served
     (serve,) = spans["session.serve"]
