@@ -22,6 +22,12 @@ A request counts once its last stage's forward returned in the window.
 - ``service_p95_ms``: per request, the summed wall time of the forwards
   that carried it; the 95th percentile (linear) over all counted.
 
+With ``--trace 1`` the profiler's trace of the window is reduced twice
+before it is deleted: by ``bench/trace.py`` (device busy time, programs,
+top operations, idle gaps) and by ``bench/spans.py`` (the device's idle
+time put down to the program's host spans, ``idle_by_span`` in the
+breakdown). The untraced run reads neither.
+
 After the window, with the program's device state freed, the outputs are
 checked: every counted request passed every stage once, on the prompt
 the mix gave it, each stage fed the previous stage's output; and for a
@@ -46,7 +52,7 @@ from repro import api
 from repro.cluster.env import RuntimeEnv
 from repro.core.mdp import Config
 
-from bench import flops, trace
+from bench import flops, spans, trace
 from bench.arrivals import MixArrivals, prompts
 from bench.harness import Cell, Result, note
 from bench.reference import transformer as ref
@@ -389,16 +395,23 @@ def run(cell: Cell, args, *, t0: float) -> Result:
     else:
         checks["sampled_requests"] = (math.inf, 0.0)
 
-    summary = None
+    summary = host = None
     if tracedir:
-        summary = trace.reduce(next(iter(_xplanes(tracedir))), program=FORWARD,
+        xplane = next(iter(_xplanes(tracedir)))
+        summary = trace.reduce(xplane, program=FORWARD,
                                name_programs=lambda ps: _name_programs(ps, calls, cfg))
+        host = spans.reduce(xplane, summary, program=FORWARD)
         shutil.rmtree(tracedir, ignore_errors=True)
     device = {"memory_peak_bytes": peak_bytes}
     breakdown = None
     if summary is not None:
         device.update(busy_s=summary.busy_s, window_s=summary.window_s)
         breakdown = {"device_ops": summary.top_ops, "idle_gaps": summary.idle_gaps}
+    if host is not None:
+        breakdown["idle_by_span"] = host.idle_by_span[:10]
+        note(f"program spans: {host.outside} of {host.forwards} forwards outside "
+             f"their dispatch-to-wait spans; skew_s {host.skew_s!r}, "
+             f"slack_s {host.slack_s!r}")
     return Result(
         metrics={"served_req_per_s": len(completed) / wall,
                  "service_p95_ms": p95,
@@ -406,7 +419,8 @@ def run(cell: Cell, args, *, t0: float) -> Result:
         checks=checks, attempted=len(completed), failed=failed, device=device,
         breakdown=breakdown,
         context={"calls": calls, "window_s": wall, "config": cfg, "mix": mix,
-                 "cap": cap, "peak": peak, "trace": summary, "setup_split": split,
+                 "cap": cap, "peak": peak, "trace": summary, "spans": host,
+                 "setup_split": split,
                  "compiles_in_window": in_window, **extra})
 
 

@@ -26,6 +26,9 @@ def make(root: Path, *, cap: int = 2) -> Path:
     shutil.copytree(REPO / "bench", root / "bench",
                     ignore=shutil.ignore_patterns(".scratch", "__pycache__", "tests"))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    drivers = {c["name"]: json.loads((REPO / c["file"]).read_text())["driver"]
+               for c in bench["configs"]}
+    served = {w["name"] for w in bench["workloads"] if drivers[w["config"]] == "serve"}
     cfg = json.loads((REPO / "bench" / "configs" / "speech2code.json").read_text())
     cfg.update(name="speech2code_smoke", widths="smoke",
                serve_config={"z": [0, 0], "f": [1, 1], "b": [cap, cap]})
@@ -47,7 +50,7 @@ def make(root: Path, *, cap: int = 2) -> Path:
     bench["workloads"].append({"name": CELL, "config": "speech2code_smoke",
                                "traffic": "tiny", "chips": 1, "why": "CPU smoke test"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
+        if served & set(m.get("workloads", ())):
             m["workloads"].append(CELL)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root

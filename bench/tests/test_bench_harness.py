@@ -62,6 +62,12 @@ def test_every_cell_resolves_to_its_files(cell):
     assert c.per_layer
     for m in c.per_layer:
         assert callable(harness.reader(m["name"]))
+    if c.config["driver"] == "train":
+        numbers = harness.driver(c).NUMBERS
+        assert set(c.limits) <= set(numbers)
+        assert {n for n in c.limits if n.startswith("update_")}
+        assert {n for n in c.limits if n.startswith("twin_")}
+        return
     assert "unaccounted_requests" in c.limits
     for i in range(len(c.config["stages"])):
         assert {f"logit_gap.stage{i}", f"logit_gap_mean.stage{i}"} & set(c.limits)
@@ -72,6 +78,8 @@ def test_configuration_files_state_what_the_program_runs():
     drv = harness.driver(harness.resolve(BENCH["workloads"][0]["name"]))
     for c in BENCH["configs"]:
         cfg = json.loads((REPO / c["file"]).read_text())
+        if cfg["driver"] != "serve":
+            continue        # a training configuration: bench/tests/test_bench_train.py
         for stage in cfg["stages"]:
             arch = ARCHS[stage["model"]].replace(dtype=stage["torch_dtype"])
             have = drv._program_view(arch, stage)
