@@ -1,12 +1,17 @@
 """The one generator of open-loop traffic that every mix file feeds.
 
 A mix states its ``rate`` (requests per virtual second) and a
-``requests`` count. The generator takes the ``requests`` quantiles of a
-unit-rate exponential as the gaps between arrivals, puts them in an
-order drawn from the seed, and divides by the rate. So every seed offers
-the same number of requests and the same gaps in another order; what the
-seed changes is which request comes when. Prompts are drawn by the
-served runtime from the process's ``seed`` (see ``prompts``).
+``requests`` count, and may state a ``gap_block`` that divides it (by
+default ``requests`` itself). The generator cuts the requests into
+blocks of ``gap_block``, takes the ``gap_block`` quantiles of a unit-rate
+exponential as the gaps between arrivals of each block, puts each block's
+gaps in an order of its own drawn from the seed, and divides by the rate.
+So every seed offers the same number of requests and the same gaps in
+another order, and every block of consecutive requests the same gaps: a
+window that serves only the mix's first blocks meets the same work
+whatever the seed. What the seed changes is which request comes when.
+Prompts are drawn by the served runtime from the process's ``seed`` (see
+``prompts``).
 """
 from __future__ import annotations
 
@@ -25,8 +30,13 @@ class MixArrivals:
     def __init__(self, mix: dict, seed: int):
         self.seed = int(seed)
         self.rate = float(mix["rate"])
+        n = int(mix["requests"])
+        block = int(mix.get("gap_block", n))
+        if block < 1 or n % block:
+            raise ValueError(f"gap_block {block} does not divide requests {n}")
         rng = np.random.default_rng([self.seed, 0x61727276])
-        gaps = rng.permutation(unit_gaps(int(mix["requests"])))
+        base = unit_gaps(block)
+        gaps = np.concatenate([rng.permutation(base) for _ in range(n // block)])
         self.times = np.cumsum(gaps) / self.rate
         self.times.flags.writeable = False
 

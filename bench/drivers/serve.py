@@ -1,7 +1,9 @@
 """Driver for a served pipeline: live stage models behind
 ``api.Session.serve``, replayed as fast as the chip allows.
 
-Set-up (``setup_s``, from process start): the session and its stage
+Set-up (``setup_s``, from process start): each stage's model family is
+found first, as the file ``bench/reference/<family>.py`` (the run stops,
+naming the file, where it is missing); then the session and its stage
 servers are built through the program's public API, each stage's weights
 drawn on the device from the seed, and every batch size that the mix
 will send a stage (the runtime replayed in virtual time without models)
@@ -32,9 +34,13 @@ After the window, with the program's device state freed, the outputs are
 checked: every counted request passed every stage once, on the prompt
 the mix gave it, each stage fed the previous stage's output; and for a
 sample of requests drawn from the seed, every served token's logit is
-compared with the float32 reference's best at its position
-(``bench/reference/transformer.py``): the widest gap or the mean gap of
-each stage, as the cell's limits file names them, is held to its limit.
+compared with the float32 reference's best at its position (the stage
+family's ``gap_program``): the widest gap or the mean gap of each stage,
+as the cell's limits file names them, is held to its limit.
+
+``context["stage_stats"]`` holds each stage server's ``stats()`` taken
+just before the window opens and just after it closes, so that a
+per-layer metric can read a program counter as the window's difference.
 """
 from __future__ import annotations
 
@@ -52,10 +58,9 @@ from repro import api
 from repro.cluster.env import RuntimeEnv
 from repro.core.mdp import Config
 
-from bench import flops, spans, trace
+from bench import flops, reference, spans, trace
 from bench.arrivals import MixArrivals, prompts
 from bench.harness import Cell, Result, note
-from bench.reference import transformer as ref
 
 SAMPLE_TOKENS = 4096        # served tokens per stage that the reference reads
 FORWARD = "jit_fwd"         # the served forward program, one per stage call
@@ -126,19 +131,7 @@ def _stage_seed(seed: int, stage: int) -> int:
 def _program_view(arch, stage: dict) -> dict:
     """The stage's sizes as the program will run them, under the
     configuration file's names, to refuse a file that says otherwise."""
-    if stage["family"] == "whisper_decoder":
-        from repro.models import whisper
-        return {"decoder_layers": arch.n_layers, "d_model": arch.d_model,
-                "decoder_attention_heads": arch.n_heads,
-                "decoder_ffn_dim": arch.d_ff, "vocab_size": arch.vocab,
-                "max_source_positions": arch.enc_len,
-                "max_target_positions": whisper.MAX_POSITIONS,
-                "torch_dtype": arch.dtype}
-    return {"num_hidden_layers": arch.n_layers, "hidden_size": arch.d_model,
-            "num_attention_heads": arch.n_heads,
-            "num_key_value_heads": arch.n_kv, "intermediate_size": arch.d_ff,
-            "vocab_size": arch.vocab, "rope_theta": arch.rope_theta,
-            "torch_dtype": arch.dtype}
+    return reference.family(stage).program_view(arch, stage)
 
 
 class CompileCounter:
@@ -166,8 +159,9 @@ def _fixed(cfg: dict) -> Config:
 
 def build(cell: Cell, seed: int):
     """The session, its stage servers and the mix, through the program's
-    public API."""
+    public API, once every stage's family is found."""
     cfg, mix = cell.config, cell.traffic
+    families = [reference.family(stage, cell.root) for stage in cfg["stages"]]
     controller = f"bench-fixed-{cfg['name']}"
     fixed = _fixed(cfg)
     api.register_controller(controller, lambda spec, pipe, params: FixedController(fixed))
@@ -183,8 +177,9 @@ def build(cell: Cell, seed: int):
         backend="runtime", real=True, seq_len=mix["seq_len"], widths=cfg["widths"])
     sess = api.Session.from_spec(spec)
     servers = api.build_executors(spec)
-    for i, (srv, stage) in enumerate(zip(servers, cfg["stages"], strict=True)):
-        have = _program_view(srv.variants[0], stage)
+    for i, (srv, fam, stage) in enumerate(zip(servers, families, cfg["stages"],
+                                              strict=True)):
+        have = fam.program_view(srv.variants[0], stage)
         want = {k: stage[k] for k in have}
         if have != want:
             raise SystemExit(f"bench: stage {i} runs {have}, the configuration "
@@ -264,15 +259,15 @@ def reference_gaps(cfg: dict, calls: list[Call], picks: list[list[tuple[int, int
     control puts first."""
     served, low = [], []
     for i, stage in enumerate(cfg["stages"]):
+        fam = reference.family(stage)
         where = [p[i] for p in picks]
         tokens = np.stack([calls[k].tokens[r] for k, r in where])
         out_tokens = np.stack([calls[k].out[r] for k, r in where])
-        weights = ref.init_weights(stage, _stage_seed(seed, i))
-        args = [weights, tokens, out_tokens]
-        if stage["family"] == "whisper_decoder":
-            args.append(np.stack([np.asarray(ref.stub_frames(stage, calls[k].batch, r))
-                                  for k, r in where]))
-        out = ref.gap_program(stage, control=control)(*args)
+        weights = fam.init_weights(stage, _stage_seed(seed, i))
+        inputs = zip(*(fam.reference_inputs(stage, calls[k].batch, r) for k, r in where))
+        args = [weights, tokens, out_tokens,
+                *(np.stack([np.asarray(x) for x in column]) for column in inputs)]
+        out = fam.gap_program(stage, control=control)(*args)
         gaps, ctl = out if control else (out, None)
         served.append(np.asarray(gaps))
         if control:
@@ -319,6 +314,7 @@ def run(cell: Cell, args, *, t0: float) -> Result:
     env = make_env([Recorder(s, i, window) for i, s in enumerate(servers)])
     sess.build_env = lambda: env        # serve() drives the env built here
 
+    stats_before = [srv.stats() for srv in servers]
     tracedir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace else None
     if tracedir:
         jax.profiler.start_trace(tracedir)
@@ -334,6 +330,7 @@ def run(cell: Cell, args, *, t0: float) -> Result:
     t_close = time.perf_counter()
     if tracedir:
         jax.profiler.stop_trace()
+    stats_after = [srv.stats() for srv in servers]
     if ran_out:
         raise SystemExit("bench: the mix ran out of requests before the window "
                          "closed; give it more requests")
@@ -421,6 +418,7 @@ def run(cell: Cell, args, *, t0: float) -> Result:
         context={"calls": calls, "window_s": wall, "config": cfg, "mix": mix,
                  "cap": cap, "peak": peak, "trace": summary, "spans": host,
                  "setup_split": split,
+                 "stage_stats": {"before": stats_before, "after": stats_after},
                  "compiles_in_window": in_window, **extra})
 
 

@@ -1,6 +1,8 @@
 """Operations and bytes that one served forward needs, counted from shapes.
 
-A forward over tokens [B, S] is charged:
+Each served family counts its own (``forward_cost`` of
+``bench/reference/<family>.py``; the count of the layer stack that the
+families share is ``bench/reference/layers.py:stack_cost``):
 - FLOPs: 2 per multiply-add of every matmul against a weight, the
   attention products (causal self-attention over the S(S+1)/2 pairs it
   needs, full cross-attention over the encoder's frames) and the output
@@ -19,7 +21,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from bench.reference.transformer import dims
+from bench.reference import family
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -33,39 +35,10 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _attention_params(d: int, heads: int, kv_heads: int) -> int:
-    hd = d // heads
-    return d * heads * hd * 2 + d * kv_heads * hd * 2
-
-
 def forward_cost(stage: dict, batch: int, seq: int) -> tuple[float, float]:
-    """(FLOPs, bytes) of one forward of ``stage`` over [batch, seq] tokens."""
-    n = dims(stage)
-    d, heads, L, V, d_ff = n["d"], n["heads"], n["layers"], n["vocab"], n["d_ff"]
-    hd = d // heads
-    item = 2 if stage["torch_dtype"] == "bfloat16" else 4
-    tokens = batch * seq
-    pairs = seq * (seq + 1) // 2
-    self_attn = _attention_params(d, heads, n["kv_heads"])
-    mlp = 2 * d * d_ff
-    biases = heads * hd + 2 * n["kv_heads"] * hd + d_ff + d
-    norms = 2 * 2 * d
-    flops = 2.0 * tokens * L * (self_attn + mlp)
-    flops += 2.0 * 2 * batch * heads * hd * pairs * L       # QK^T and PV
-    flops += 2.0 * tokens * d * V                           # output projection
-    per_layer = self_attn + mlp + biases + norms
-    moved = batch * seq * 4 * 2                             # tokens in and out
-    gathered = min(V, tokens) * d
-    if stage["family"] == "whisper_decoder":
-        T = n["frames"]
-        flops += 2.0 * tokens * L * 2 * d * d               # cross q and o
-        flops += 2.0 * batch * T * L * 2 * d * d            # cross k and v
-        flops += 2.0 * 2 * batch * heads * hd * seq * T * L
-        per_layer += 4 * d * d + 3 * d + norms // 2
-        moved += batch * T * d * item                       # encoder frames
-        gathered += seq * d                                 # position rows
-    weights = L * per_layer + d * V + 2 * d
-    return flops, float(weights * item + gathered * item + moved)
+    """(FLOPs, bytes) of one forward of ``stage`` over [batch, seq] tokens,
+    as the stage's family counts them."""
+    return family(stage).forward_cost(stage, batch, seq)
 
 
 def roofline_s(stage: dict, batch: int, seq: int, peak: dict) -> tuple[float, str]:

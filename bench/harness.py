@@ -7,9 +7,12 @@ and prints the result.
     bench/limits/<cell>.json  the limits the cell's outputs are held to
     bench/drivers/<driver>.py ``run(cell, args, clock) -> Result``
     bench/metrics/<metric>.py ``read(result) -> float | None``
+    bench/reference/<family>.py  a served stage's model family: its
+                              reference, program view and cost count
+                              (``bench/reference/__init__.py``)
 
-A later cell, mix or metric is added by adding files and entries; nothing
-here names one.
+A later cell, mix, metric or served model family is added by adding files
+and entries; nothing here names one.
 """
 from __future__ import annotations
 

@@ -29,7 +29,7 @@ activations, gradients and optimizer state all bfloat16; ``"int8"``,
 float32 state with every matmul of the forward and the backward computed
 in int8 (each operand rounded to int8 along the contracted axis, one
 absmax scale per slice, the products summed exactly, as
-``bench/reference/transformer.py``'s control does).
+``bench/reference/layers.py``'s control does).
 """
 from __future__ import annotations
 

@@ -178,3 +178,20 @@ def test_every_seed_gets_the_same_gaps_in_another_order():
     np.testing.assert_allclose(gaps[0], np.sort(unit_gaps(500)) / 4.0, rtol=1e-9)
     assert not np.array_equal(a.times, b.times)
     assert a.rates(3).tolist() == [4.0, 4.0, 4.0]
+
+
+def test_every_gap_block_holds_the_same_gaps_in_another_order():
+    from bench.arrivals import MixArrivals, unit_gaps
+    mix = {"rate": 2.0, "requests": 500, "gap_block": 50}
+    a, b = MixArrivals(mix, 3), MixArrivals(mix, 2**31 + 7)
+    for m in (a, b):
+        blocks = np.diff(np.concatenate([[0.0], m.times])).reshape(10, 50)
+        np.testing.assert_allclose(np.sort(blocks, axis=1),
+                                   np.tile(np.sort(unit_gaps(50)) / 2.0, (10, 1)), rtol=1e-9)
+        assert len({tuple(np.argsort(row)) for row in blocks}) == 10
+    assert not np.array_equal(a.times, b.times)
+    whole = {"rate": 2.0, "requests": 500}
+    np.testing.assert_array_equal(MixArrivals({**whole, "gap_block": 500}, 3).times,
+                                  MixArrivals(whole, 3).times)
+    with pytest.raises(ValueError, match="does not divide"):
+        MixArrivals({**whole, "gap_block": 64}, 3)

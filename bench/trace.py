@@ -9,9 +9,10 @@ metrics read, for the window the harness marked with a host span.
   ops and are not counted.
 - Programs: the ``XLA Modules`` line of the first device, one event per
   execution of a compiled program, in order; the caller names them.
-- Top operations: device time per (program name, HLO op name without its
-  number), the ten largest. An op that another op starts inside (a
-  ``while`` loop, whose body's ops follow it) is not counted twice.
+- Operations: device time per (program label, HLO op name without its
+  number), every one (``op_seconds``) and the ten largest (``top_ops``).
+  An op that another op starts inside (a ``while`` loop, whose body's ops
+  follow it) is not counted twice.
 - Idle gaps: the stretches of the window in which no op ran, each named by
   the programs on either side of it (what the host was doing between
   them), summed per name, the ten largest.
@@ -57,6 +58,7 @@ class Summary:
     programs: list[Program]
     top_ops: list[list]
     idle_gaps: list[list]
+    op_seconds: dict[str, float]        # "<program label>/<op>" -> seconds
 
 
 def program_name(event_name: str) -> str:
@@ -179,4 +181,4 @@ def reduce(path: str | Path, *, name_programs=None, program: str | None = None,
     return Summary(window_s=(w1 - w0) * 1e-9, skew_s=shift * 1e-9,
                    busy_s=sum(busy) / len(busy) * 1e-9, devices=len(devices),
                    programs=programs, top_ops=_top(op_totals),
-                   idle_gaps=_top(gap_totals))
+                   idle_gaps=_top(gap_totals), op_seconds=dict(op_totals))
