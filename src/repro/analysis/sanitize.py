@@ -75,7 +75,7 @@ def checked(fn=None, *, errors=None):
 
     ``errors`` narrows the check set for functions where part of the
     default instrumentation cannot be applied (checkify's OOB rule fails
-    to transform the batched ``dynamic_update_slice`` in the runtime
+    to transform the batched scatter (the queue enqueue) in the runtime
     twin's vmapped event loop — those entry points keep
     NaN + div checks and note why inline).
 

@@ -19,8 +19,8 @@ one event at a time, always processing the earliest of
 continuous arrival times, exact ties are measure zero). Neither arrivals
 nor transfer deliveries are events. The pre-generated arrival array is
 sorted and immutable, so stage 0's queue is *virtual* — a head pointer into
-the arrival array, which ``init_state`` lays into queue-buffer row 0 so
-every stage reads through one uniform window; a dispatch counts how many
+the arrival array, which ``init_state`` lays into queue-buffer rows 0 and
+1 so every stage reads through one uniform window; a dispatch counts how many
 arrivals have landed within its 2B-wide head window.
 Cross-node transfers get the same treatment: a forwarded completion writes
 its batch into the next stage's queue immediately, stamped with its
@@ -41,17 +41,29 @@ loop rather than via ``vmap`` — ``while_loop``'s batching rule wraps every
 carry array in a per-iteration ``select(done, old, new)``, which copies
 the multi-MB queue buffer once per event; with a scalar ``jnp.any``
 condition and self-masking envs the buffer keeps a single consumer (its
-enqueue scatter) and XLA mutates it in place. Three things keep the loop
-body lean on CPU, where it is kernel-launch bound (~35 fused kernels per
-event at a microsecond each):
+enqueue scatter), which writes it in place.
+
+The queue buffer's layout decides what the loop moves per event. Each env
+holds it as ``[2S, Q]``: one row per (stage, column), so the queue axis Q
+is the minor, lane-dense one and every access runs along it, as element
+gathers and one element scatter with a per-env (row, column) index. On a
+TPU a minor axis of 2 (the ``[S, Q, 2]`` layout) is padded to 128 lanes,
+and each batched read wanted a tiling other than the one the loop
+carried, so XLA relaid out the whole buffer twice per event; a vmapped
+``dynamic_slice`` / ``dynamic_update_slice`` with per-env offsets lowers
+to a loop over the envs, of a few small kernels each. Per-element indices
+lower to one gather or scatter for all envs.
+
+With the buffer moving no more than its accesses touch, an event's cost
+is the number of small kernels the body launches. Three things keep
+that number down:
 
 - the queue buffer sees exactly one scatter per event (the forward
   enqueue); everything else is gathers and one-hot masked vector math.
   In-flight batches pin their *head index* (``fl_head``), not their
   contents — the buffer is append-only, so one gather at completion
   recovers the batch's arrival times, where pinning the times themselves
-  would cost a second scatter (vmapped ``dynamic_update_slice`` lowers to
-  a sequential per-env loop on CPU XLA — gathers don't);
+  would cost a second scatter;
 - ``select`` runs on carried per-stage head / batch-full delivery stamps
   (``r_head`` / ``r_full``, refreshed from the buffer once per interval,
   maintained incrementally per event), so picking the next event never
@@ -101,7 +113,7 @@ COLD_START_SECONDS = COLD_START_FRACTION * ADAPTATION_INTERVAL
 DEFAULT_MAX_WAIT = 0.25          # mirrors serving.runtime.DEFAULT_MAX_WAIT
 _ARRIVAL_BUCKET = 512            # arrival arrays pad to multiples of this
 # guaranteed inf-padding at the tail of every arrival array, so the event
-# loop's 2B-wide head window is always a plain in-bounds dynamic_slice
+# loop's 2B-wide head window always reads in bounds
 # (requires 2 * b_max <= _ARRIVAL_PAD — checked in init_state)
 _ARRIVAL_PAD = 64
 
@@ -120,15 +132,16 @@ class RuntimeState(NamedTuple):
     """The twin's full event-loop state (one environment)."""
     now: jax.Array           # f32 virtual clock
     arr_idx: jax.Array       # i32 arrivals landed by the last boundary
-    q_buf: jax.Array         # [S, Q, 2] f32 append-only queue:
-                             #   [..., 0] original arrival time
-                             #   [..., 1] delivery time at this stage
+    q_buf: jax.Array         # [2S, Q] f32 append-only queues, two rows
+                             #   per stage so every access runs along Q:
+                             #   row 2s    original arrival time
+                             #   row 2s+1  delivery time at stage s
                              #     (completion time + hop; a stamp in the
                              #      future means the batch is still in
                              #      cross-node transfer)
-                             #   (row 0 holds the episode's arrival array
-                             #    in both columns — stage 0's "queue" —
-                             #    so every read is uniform across stages)
+                             #   (rows 0 and 1 both hold the episode's
+                             #    arrival array — stage 0's "queue" — so
+                             #    every read is uniform across stages)
     q_head: jax.Array        # [S] i32 (monotone, no wraparound; head 0
                              #   indexes the episode's arrival array)
     q_len: jax.Array         # [S] i32 enqueued requests (head..head+len)
@@ -218,14 +231,13 @@ def init_state(tables: PipelineTables, ep: EpisodeArrivals) -> RuntimeState:
     z0 = jnp.zeros(S, jnp.int32)
     f0 = jnp.ones(S, jnp.int32)
     slot_speed, hop_next = _install_placement(tables, z0, f0)
-    # stage 0's queue row holds the episode's (inf-padded) arrival array in
-    # both columns: a request's stage-0 "delivery" is its arrival. The last
-    # B lanes stay inf — that's where masked-off enqueue writes land, and
-    # no read reaches past times' own _ARRIVAL_PAD inf tail before it
+    # stage 0's two queue rows both hold the episode's (inf-padded) arrival
+    # array: a request's stage-0 "delivery" is its arrival. The last B
+    # lanes stay inf — that's where masked-off enqueue writes land, and no
+    # read reaches past times' own _ARRIVAL_PAD inf tail before it
     row0 = jnp.full(Q, jnp.inf, jnp.float32).at[:ep.times.shape[0]].set(
         ep.times)
-    q_buf = jnp.zeros((S, Q, 2), jnp.float32)
-    q_buf = q_buf.at[0, :, 0].set(row0).at[0, :, 1].set(row0)
+    q_buf = jnp.zeros((2 * S, Q), jnp.float32).at[:2].set(row0)
     return RuntimeState(
         now=jnp.float32(0.0), arr_idx=jnp.int32(0),
         q_buf=q_buf,
@@ -268,18 +280,25 @@ def _advance(tables: PipelineTables, state: RuntimeState,
     invoke ``while_loop``'s batching rule, which wraps every carry array in
     a per-iteration ``select(done, old, new)`` — a full copy of the
     multi-MB queue buffer per event. With a scalar ``jnp.any`` condition
-    the queue buffer keeps a single consumer (its enqueue scatter), XLA
-    updates it in place, and envs that have drained their interval mask
+    the queue buffer keeps a single consumer (its enqueue scatter), which
+    writes it in place, and envs that have drained their interval mask
     their own effects (~5x wall clock on CPU at 32 envs).
+
+    The buffer is ``[E, 2S, Q]`` (row 2s arrival times, row 2s+1 delivery
+    stamps) and is read and written only by per-element (row, column)
+    indices along Q. That keeps it out of any copy: on a TPU the former
+    ``[S, Q, 2]`` layout padded its minor axis of 2 to 128 lanes, and each
+    batched read relaid out the whole buffer, twice per event.
     """
     S = tables.n_tasks
     R = tables.replica_slots.shape[0]
     B = tables.batch_slots.shape[0]
-    Q = state.q_buf.shape[2]
+    Q = state.q_buf.shape[-1]
     iota_s = jnp.arange(S, dtype=jnp.int32)
     iota_r = jnp.arange(R, dtype=jnp.int32)
     iota_b = jnp.arange(B, dtype=jnp.int32)
     iota_b2 = jnp.arange(2 * B, dtype=jnp.int32)
+    iota_2 = jnp.arange(2, dtype=jnp.int32)
     # per-interval constants: the live configuration is fixed between
     # reconfigurations, so service coefficients resolve once per _advance
     a_z = jax.vmap(lambda z: _gather(tables.alpha, z))(state.z)
@@ -300,9 +319,9 @@ def _advance(tables: PipelineTables, state: RuntimeState,
         enqueue writes, so ``select`` never touches the big buffer. The
         inf tails keep head + b - 1 in bounds and return inf when stage
         0's remaining arrivals can't fill a batch."""
-        r_head = st.q_buf[iota_s, jnp.minimum(st.q_head, Q - 1), 1]
-        r_full = st.q_buf[iota_s,
-                          jnp.minimum(st.q_head + st.b - 1, Q - 1), 1]
+        r_head = st.q_buf[2 * iota_s + 1, jnp.minimum(st.q_head, Q - 1)]
+        r_full = st.q_buf[2 * iota_s + 1,
+                          jnp.minimum(st.q_head + st.b - 1, Q - 1)]
         return st._replace(r_head=r_head, r_full=r_full)
 
     def select(st: RuntimeState):
@@ -351,8 +370,7 @@ def _advance(tables: PipelineTables, state: RuntimeState,
         # the batch's arrival times still sit where they were dispatched
         # from: the buffer is append-only (slab writes land at tails past
         # them), so the pinned head index recovers them with one gather
-        cmp_orig = jax.lax.dynamic_slice(
-            st.q_buf, (s_cmp, hd_cmp, 0), (1, B, 2))[0, :, 0]
+        cmp_orig = st.q_buf[2 * s_cmp, hd_cmp + iota_b]
         last = s_cmp == S - 1
         fl_finish = jnp.where(is_cmp & oh_cmp, INF, st.fl_finish)
         done = is_cmp & last
@@ -367,18 +385,18 @@ def _advance(tables: PipelineTables, state: RuntimeState,
 
         # -- the one write on the big buffer: a forwarded completion puts
         #    its batch into s+1 (stamp = delivery time, now + hop) as one
-        #    contiguous dynamic_update_slice. Lanes past the batch land
-        #    beyond the new tail and are overwritten before any read;
-        #    masked-off events write at (0, Q - B) — the inf headroom past
+        #    (2, B) element scatter into rows 2(s+1), 2(s+1)+1 at the
+        #    stage's tail. Lanes past the batch land beyond the new tail
+        #    and are overwritten before any read; masked-off events write
+        #    at rows (0, 1), column Q - B — the inf headroom past
         #    stage 0's arrival array, which no window read ever reaches ----
         w_s, w_k = s_next, k_cmp
         tail = row_s(st.q_head + st.q_len, w_s)
-        vals = jnp.stack([cmp_orig,
-                          jnp.broadcast_to(now + hop_cmp, (B,))], axis=-1)
-        q_buf = jax.lax.dynamic_update_slice(
-            st.q_buf, vals[None],
-            (jnp.where(forward, w_s, 0), jnp.where(forward, tail, Q - B),
-             0))
+        vals = jnp.stack([cmp_orig, jnp.broadcast_to(now + hop_cmp, (B,))])
+        w_row = jnp.where(forward, 2 * w_s, 0)
+        w_col = jnp.where(forward, tail, Q - B)
+        q_buf = st.q_buf.at[w_row + iota_2[:, None],
+                            w_col + iota_b[None, :]].set(vals)
 
         # -- completion -> dispatch fusion: replay ``select``'s dispatch
         #    timers on the post-completion state — pure vector math on the
@@ -424,10 +442,7 @@ def _advance(tables: PipelineTables, state: RuntimeState,
         hq = jnp.sum(jnp.where(oh_d[None, :],
                                jnp.stack([st.q_head, q_len_mid]), 0), axis=1)
         head_d = hq[0]
-        q_slice = jax.lax.dynamic_slice(
-            q_buf, (s_disp, head_d, 0), (1, 2 * B, 2)).reshape(2 * B, 2)
-        orig_src = q_slice[:, 0]
-        stamp = q_slice[:, 1]
+        stamp = q_buf[2 * s_disp + 1, head_d + iota_b2]
         # stage 0's depth is virtual (its lanes past the arrivals are inf,
         # so the stamp check alone bounds the pop)
         in_q = (s_disp == 0) | (iota_b2 < hq[1])
@@ -648,7 +663,7 @@ def rollout(params, tables: PipelineTables, ep: EpisodeArrivals,
 
 
 # NaN + div only: checkify's OOB rule can't transform the batched
-# dynamic_update_slice in the vmapped event loop
+# scatter (the queue enqueue) in the vmapped event loop
 @sanitize.checked(errors=sanitize.NAN_DIV_ERRORS)
 @partial(jax.jit, static_argnames=("n_steps", "weights", "max_wait",
                                    "greedy"))

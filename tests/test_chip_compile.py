@@ -2,15 +2,12 @@
 starcoder2-3b's head shapes, and one published-width starcoder2-3b decode
 step. Nothing runs — the TPU compiler, installed here, compiles for a chip
 that is described and not attached, and refuses what the chip would
-(block tiling, VMEM, memory). The topology is described inside a
-module-scoped fixture, never while a module is imported, so every pytest
-worker collects the same tests and only the one given this file loads the
-TPU library.
+(block tiling, VMEM, memory). The chip comes from the ``one_chip``
+fixture of ``conftest.py``.
 """
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from repro.configs import ARCHS
 from repro.kernels.decode_attention import decode_attention
@@ -21,34 +18,6 @@ from repro.models.config import InputShape
 SC = ARCHS["starcoder2-3b"]
 H, HKV, D = SC.n_heads, SC.n_kv, SC.head_dim          # 24, 2, 128
 CACHE = 1024
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TPU_LOG_DIR", "disabled")
-        try:
-            desc = topologies.get_topology_desc(platform="tpu",
-                                                topology_name="v5e:2x2")
-        except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        # executables compiled for a described chip are written to the
-        # persistent cache but cannot be read back without one: keep the
-        # cache off so these compiles stay silent
-        enabled = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        cc.reset_cache()
-        yield desc
-        jax.config.update("jax_enable_compilation_cache", enabled)
-        cc.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, dtype, sharding):

@@ -3,8 +3,11 @@ replay equivalence with the reference ``RuntimeEnv``/``ServingRuntime`` loop
 across all registered pipelines (including the placement-aware
 ``serve3-hetero`` on the ``edge-hetero-3`` cluster), arrival precomputation,
 closed-loop vec_rollout invariants, the OPDTrainer vec-runtime branch, and
-``train_backend="runtime"`` reproducibility through the Session facade."""
+``train_backend="runtime"`` reproducibility through the Session facade,
+and the event loop's compiled shape on a described TPU v5e."""
 import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +24,7 @@ from repro.core.mdp import QoSWeights
 from repro.serving import make_arrivals
 
 WEIGHTS = QoSWeights()
+GOLDEN = os.path.join(os.path.dirname(__file__), "data", "runtime_vec_serve3h.npz")
 HORIZON = 60
 N_STEPS = HORIZON // 10
 
@@ -215,6 +219,39 @@ class TestVecRollout:
         assert np.allclose(np.asarray(out["rewards"][i]), ref_r, atol=0.15)
 
 
+def _golden_case():
+    """serve3-hetero, 4 envs of bursty 25 req/s, 12 intervals: the case
+    ``tests/data/runtime_vec_serve3h.npz`` holds the outputs of (written with
+    ``np.savez(path, **outputs)`` by the twin's earlier ``[S, Q, 2]`` queue
+    layout, before the buffer became one row per stage and column)."""
+    pipe = api.get_pipeline("serve3-hetero").build()
+    tables = vecenv.tables_from_pipeline(pipe)
+    env = RuntimeEnv(pipe, make_arrivals("bursty", rate=25, seed=0), horizon=120)
+    params = init_policy(jax.random.PRNGKey(0), env.state_dim, head_sizes(pipe))
+    eps = rv.stack_episodes(
+        [
+            rv.episode_arrivals(make_arrivals("bursty", rate=25, seed=i), 120)
+            for i in range(4)
+        ]
+    )
+    keys = jax.vmap(lambda s: jax.random.fold_in(jax.random.PRNGKey(9), s))(
+        jnp.arange(4)
+    )
+    return rv.vec_rollout(params, tables, eps, keys, n_steps=12, weights=WEIGHTS)
+
+
+def test_vec_rollout_reproduces_recorded_episode_bit_for_bit():
+    """Where the queue's bytes live changes no clock, event order or float:
+    every output equals the recorded one bit for bit."""
+    want = np.load(GOLDEN)
+    got = _golden_case()
+    assert sorted(want.files) == sorted(got)
+    for k in want.files:
+        g = np.asarray(got[k])
+        assert g.dtype == want[k].dtype and g.shape == want[k].shape, k
+        assert g.tobytes() == want[k].tobytes(), k
+
+
 class TestTrainerVecRuntime:
     def _factory(self, pipe):
         def arrivals(seed):
@@ -355,3 +392,112 @@ class TestSessionRuntimeBackend:
         assert h1 == h2
         same = jax.tree.map(lambda a, b: bool((a == b).all()), p1, p2)
         assert all(jax.tree.leaves(same))
+
+
+def _hlo_computations(text):
+    """{computation name: (header line, body lines)} of an HLO module."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = (line, [])
+        elif name is not None and line != "}":
+            comps[name][1].append(line)
+    return comps
+
+
+def _reachable(comps, root):
+    """``root`` and every computation it calls, transitively."""
+    seen, todo = {root}, [root]
+    while todo:
+        for line in comps[todo.pop()][1]:
+            for m in re.finditer(
+                r"(?:calls|body|condition|to_apply|branch_computations)=\{?%([\w.\-]+)",
+                line,
+            ):
+                if m.group(1) in comps and m.group(1) not in seen:
+                    seen.add(m.group(1))
+                    todo.append(m.group(1))
+    return seen
+
+
+def _elements(shape):
+    """Element count of an HLO array type's dimensions, "64,6,3104"."""
+    return int(np.prod([int(d) for d in shape.split(",") if d]))
+
+
+def test_event_loop_relays_out_no_queue_buffer_on_v5e(one_chip):
+    """At the training cell's shapes (64 envs of 3072 arrival slots, b_max
+    32, three stages, 12 intervals), the event loop's compiled body holds no
+    copy of the whole queue buffer and no per-environment loop over it.
+
+    On a TPU a minor axis of 2 is padded to 128 lanes: with the buffer as
+    [S, Q, 2] each batched read wanted another tiling than the loop carried,
+    and XLA relaid out the whole buffer twice per event."""
+    E, n_cap, n_steps = 64, 3072, 12
+    pipe = api.get_pipeline("serve3-hetero").build()
+    tables = vecenv.tables_from_pipeline(pipe)
+    env = RuntimeEnv(pipe, make_arrivals("bursty", rate=25, seed=0), horizon=120)
+    params = jax.eval_shape(
+        lambda k: init_policy(k, env.state_dim, head_sizes(pipe)),
+        jax.random.PRNGKey(0),
+    )
+    eps = rv.EpisodeArrivals(
+        times=jax.ShapeDtypeStruct((E, n_cap), jnp.float32),
+        arrived=jax.ShapeDtypeStruct((E, n_steps), jnp.float32),
+        load_obs=jax.ShapeDtypeStruct((E, n_steps), jnp.float32),
+    )
+    keys = jax.ShapeDtypeStruct((E, 2), jnp.uint32)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree
+        )
+
+    text = (
+        rv.vec_rollout.__wrapped__.lower(
+            on_chip(params),
+            on_chip(tables),
+            on_chip(eps),
+            on_chip(keys),
+            n_steps=n_steps,
+            weights=WEIGHTS,
+        )
+        .compile()
+        .as_text()
+    )
+    S, B = tables.n_tasks, tables.batch_slots.shape[0]
+    n_queue = E * S * (n_cap + B) * 2
+    comps = _hlo_computations(text)
+
+    def carries_queue(header):
+        return any(
+            _elements(d) == n_queue for d in re.findall(r"f32\[([\d,]*)\]", header)
+        )
+
+    # bodies of the whiles whose carry holds the queue buffer, nested: the
+    # interval scan, then the event loop inside its body
+    loops = set()
+    for _, lines in comps.values():
+        for line in lines:
+            m = re.search(r" while\(.*body=%([\w.\-]+)", line)
+            if m and carries_queue(comps[m.group(1)][0]):
+                loops.add(m.group(1))
+    depth = {
+        body: sum(body in _reachable(comps, other) for other in loops) - 1
+        for body in loops
+    }
+    event_loops = [b for b, d in depth.items() if d == 1]
+    assert len(event_loops) == 1, depth
+    inside = _reachable(comps, event_loops[0])
+    copies = [
+        line.strip()[:160]
+        for c in inside
+        for line in comps[c][1]
+        for d in re.findall(r"= f32\[([\d,]*)\]\S* copy\(", line)
+        if _elements(d) == n_queue
+    ]
+    assert not copies, copies
+    per_env = sorted(loops & inside - {event_loops[0]})
+    assert not per_env, per_env
