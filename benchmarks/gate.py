@@ -2,8 +2,8 @@
 fraction of the committed baseline.
 
     python -m benchmarks.gate CURRENT.json \\
-        --baseline experiments/results/train_throughput.json \\
-        --metric vectorized.32.steps_per_s --min-ratio 0.5 \\
+        --baseline experiments/results/stage_calibration.json \\
+        --metric cache.hit_rate_repeat --min-ratio 0.9 \\
         --metric fit_mre_mean --max-ratio 4.0
 
 ``--metric`` is a dotted path into the JSON payload; repeat it to gate

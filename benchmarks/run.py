@@ -1,5 +1,5 @@
-"""Benchmark harness — one module per paper table/figure plus the roofline
-collector and the training-throughput benchmark.
+"""Paper-figure reproductions — one module per paper table/figure, plus the
+stage calibration.
 ``PYTHONPATH=src python -m benchmarks.run [--quick] [--out DIR] [--only fig3]``
 
 Emits ``benchmark,metric,value,reference`` CSV (reference = the paper claim
@@ -21,9 +21,7 @@ def main() -> None:
     ap.add_argument(
         "--only",
         default=None,
-        help="comma-separated subset: "
-        "fig3,fig45,fig6,fig7,roofline,runtime,train,"
-        "runtime_train,telemetry,fleet,calibration",
+        help="comma-separated subset: fig3,fig45,fig6,fig7,calibration",
     )
     args = bench_args(parser=ap)
 
@@ -32,25 +30,13 @@ def main() -> None:
         fig45_workloads,
         fig6_decision_time,
         fig7_convergence,
-        fleet_throughput,
-        roofline,
-        runtime_throughput,
-        runtime_train_throughput,
         stage_calibration,
-        telemetry_queries,
-        train_throughput,
     )
     suites = {
         "fig3": fig3_predictor.run,
         "fig45": fig45_workloads.run,
         "fig6": fig6_decision_time.run,
         "fig7": fig7_convergence.run,
-        "roofline": roofline.run,
-        "runtime": runtime_throughput.run,
-        "train": train_throughput.run,
-        "runtime_train": runtime_train_throughput.run,
-        "telemetry": telemetry_queries.run,
-        "fleet": fleet_throughput.run,
         "calibration": stage_calibration.run,
     }
     wanted = args.only.split(",") if args.only else list(suites)

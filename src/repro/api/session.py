@@ -208,7 +208,6 @@ class Session:
         # build_env() returns a freshly reset env — no second reset needed
         steps: dict[str, list] = {k: [] for k in _STEP_KEYS}
         rewards, configs, decide_walls = [], [], []
-        wall0 = time.perf_counter()
         done = False
         with self._sanitize_scope(), TraceAnnotation("session.serve"):
             while not done:
@@ -229,7 +228,6 @@ class Session:
             summary["submitted"] = env.submitted
             summary["switches"] = env.runtime.switch_count
             summary["utilization"] = env.runtime.utilization()
-            summary["virtual_now"] = env.runtime.now
             if env.executors:
                 summary["executors"] = [s.stats() for s in env.executors]
         self._report = {
@@ -240,7 +238,6 @@ class Session:
             "rewards": rewards,
             "configs": configs,
             "decide_wall_s": decide_walls,
-            "serve_wall_s": time.perf_counter() - wall0,
             "summary": {k: (v.tolist() if isinstance(v, np.ndarray) else v)
                         for k, v in summary.items()},
             **{k: v for k, v in steps.items()},
@@ -359,7 +356,6 @@ class FleetSession:
                                             / max(wall, 1e-9))
         self._report = {
             "fleet_spec": self.spec.to_dict(),
-            "serve_wall_s": wall,
             "rewards": rewards,
             "shed_per_interval": sheds,
             "summary": {k: (v.tolist() if isinstance(v, np.ndarray) else v)

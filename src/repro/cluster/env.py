@@ -17,13 +17,12 @@ default config, predictor hook):
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from repro.cluster.monitor import Monitor
 from repro.core.controller import Observation
-from repro.core.mdp import (ADAPTATION_INTERVAL, COLD_START_FRACTION, Config,
+from repro.core.mdp import (ADAPTATION_INTERVAL, COLD_START_FRACTION,
+                            COLD_START_SECONDS, DEFAULT_MAX_WAIT, Config,
                             Pipeline, QoSWeights, accuracy_and_cost,
                             analytic_pipeline_latency, evaluate, placement_for,
                             resource_usage, resources_feasible,
@@ -245,7 +244,6 @@ class RuntimeEnv(_ConfigEnvBase):
         # (multi-tenant fleets; do not reset() a shared-loop env twice —
         # the superseded runtime's events would stay heaped); ``rid_base``
         # offsets request ids so tenants stay distinguishable in telemetry.
-        from repro.serving.runtime import DEFAULT_MAX_WAIT
         self.pipe = pipe
         self.arrivals = arrivals
         self.horizon = int(horizon)
@@ -288,23 +286,20 @@ class RuntimeEnv(_ConfigEnvBase):
 
     def begin_step(self, action: Config):
         """Apply ``action`` without advancing time. Returns the pending
-        interval ``(t0, t1, switched, apply_wall_s)`` for ``finish_step``.
+        interval ``(t0, t1, switched)`` for ``finish_step``.
         Split out so a fleet can reconfigure *every* tenant before the
         shared event loop advances any of them through the interval."""
         rt = self.runtime
         self.cfg = action
         t0 = rt.now
         t1 = t0 + ADAPTATION_INTERVAL
-        wall0 = time.perf_counter()
-        switched = rt.apply_config(
-            action, cold_start=COLD_START_FRACTION * ADAPTATION_INTERVAL)
-        apply_wall_s = time.perf_counter() - wall0
-        return t0, t1, switched, apply_wall_s
+        switched = rt.apply_config(action, cold_start=COLD_START_SECONDS)
+        return t0, t1, switched
 
     def finish_step(self, pending):
         """Score the interval opened by ``begin_step`` after the event loop
         has advanced past ``t1`` (scores ``self.cfg``)."""
-        t0, t1, switched, apply_wall_s = pending
+        t0, t1, switched = pending
         rt, w, action = self.runtime, self.w, self.cfg
 
         tel = rt.telemetry
@@ -339,7 +334,6 @@ class RuntimeEnv(_ConfigEnvBase):
                 "throughput": m["T"], "excess": m["E"], "demand": demand,
                 "processed": completed, "infeasible": infeasible,
                 "switched": switched, "migrations": rt.last_migrations,
-                "apply_wall_s": apply_wall_s,
                 "backlog": rt.in_system,
                 "shed": tel.shed_in(t0, t1),
                 "queue_depths": rt.queue_depths(),

@@ -32,6 +32,9 @@ if TYPE_CHECKING:                       # core must not import cluster at
 
 ADAPTATION_INTERVAL = 10          # seconds between decisions (paper §VI-B)
 COLD_START_FRACTION = 0.3         # capacity lost in the interval after a switch
+# a switched stage serves nothing for this many seconds (3.0)
+COLD_START_SECONDS = COLD_START_FRACTION * ADAPTATION_INTERVAL
+DEFAULT_MAX_WAIT = 0.25           # s a request may wait before a partial batch fires
 
 
 @dataclass(frozen=True)

@@ -9,13 +9,13 @@ Rollout collection has two engines:
   expert (host-side coordinate descent);
 - vectorized analytic (``num_envs > 1``): the pure-JAX ``core.vecenv``
   engine rolls ``num_envs`` analytic environments per episode in one jitted
-  scan-over-vmap call, with scan-based GAE (``benchmarks/train_throughput``
-  measures the speedup and CI gates it);
+  scan-over-vmap call, with scan-based GAE;
 - vectorized runtime (``vec_runtime`` arrivals factory): the
   ``core.runtime_vec`` discrete-event twin rolls closed-loop episodes on
   the *runtime* dynamics — queues, batch timeouts, cold starts — entirely
   inside one jitted call, never constructing a per-env ``RuntimeEnv``
-  (``benchmarks/runtime_train_throughput`` measures the speedup).
+  (the ``opd-serve3h.train`` cell of ``BENCHMARK.json`` measures it on the
+  chip).
 """
 from __future__ import annotations
 

@@ -3,8 +3,7 @@
 The reactive loop (OPD or any baseline) only reacts *after* the load moves:
 a burst at t means the controller upsizes at the next adaptation interval
 and then pays ``COLD_START_SECONDS`` of stage unavailability exactly while
-the queue is deepest — the cold start dominates p95/p99 on bursty traces
-(``runtime_throughput.json``).
+the queue is deepest — the cold start dominates p95/p99 on bursty traces.
 
 ``ProactiveController`` wraps any inner Controller and uses the env's
 multi-horizon forecasts (``Observation.forecasts``, from

@@ -102,15 +102,13 @@ import jax.numpy as jnp
 import numpy as np  # reprolint: ignore[RPL002] host-side arrival-array prep only (episode_arrivals/stack_episodes)
 
 from repro.analysis import sanitize
-from repro.core.mdp import (ADAPTATION_INTERVAL, COLD_START_FRACTION,
-                            QoSWeights)
+from repro.core.mdp import (ADAPTATION_INTERVAL, COLD_START_SECONDS,
+                            DEFAULT_MAX_WAIT, QoSWeights)
 from repro.core.policy import apply_policy, sample_action
 from repro.core.vecenv import (PipelineTables, _gather, _placement,
                                decode_action, observe_cfg)
 
 INF = jnp.float32(jnp.inf)
-COLD_START_SECONDS = COLD_START_FRACTION * ADAPTATION_INTERVAL
-DEFAULT_MAX_WAIT = 0.25          # mirrors serving.runtime.DEFAULT_MAX_WAIT
 _ARRIVAL_BUCKET = 512            # arrival arrays pad to multiples of this
 # guaranteed inf-padding at the tail of every arrival array, so the event
 # loop's 2B-wide head window always reads in bounds

@@ -1,6 +1,5 @@
-from repro.serving.batcher import (Batcher, ContinuousBatcher, Request,
-                                   stack_tokens)
-from repro.serving.engine import StageServer, PipelineServer
+from repro.serving.batcher import ContinuousBatcher, Request, stack_tokens
+from repro.serving.engine import StageServer
 from repro.serving.arrivals import (ArrivalProcess, PoissonArrivals,
                                     TraceArrivals, BurstyArrivals,
                                     RampArrivals, make_arrivals,

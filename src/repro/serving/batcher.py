@@ -1,17 +1,12 @@
 """Request batching: each pipeline stage has a centralized queue (paper
 §III-A).
 
-Two batchers:
-
-- ``Batcher`` — the simple drain-the-queue batcher used by the blocking
-  ``PipelineServer`` path. It dispatches the *actual* number of pending
-  requests (up to ``batch_size``); no tail padding — padded rows used to
-  repeat the last request's tokens and waste a full batch of compute on
-  mostly-duplicate work.
-- ``ContinuousBatcher`` — the event-driven runtime's batcher: requests are
-  timestamped on enqueue and a batch dispatches when it is *full* or when the
-  oldest request has waited ``max_wait`` virtual seconds (timeout-or-full,
-  the InferLine/clipper-style continuous batching discipline).
+``ContinuousBatcher`` is the event-driven runtime's batcher: requests are
+timestamped on enqueue and a batch dispatches when it is *full* or when the
+oldest request has waited ``max_wait`` virtual seconds (timeout-or-full, the
+InferLine/clipper-style continuous batching discipline). A batch holds the
+*actual* number of pending requests (up to ``batch_size``), with no tail
+padding: ``stack_tokens`` stacks their prompts.
 """
 from __future__ import annotations
 
@@ -45,27 +40,6 @@ def stack_tokens(reqs: list[Request], seq_len: int) -> np.ndarray:
         src = req.tokens[:seq_len]
         toks[i, :len(src)] = src
     return toks
-
-
-class Batcher:
-    def __init__(self, batch_size: int, seq_len: int):
-        self.batch_size = batch_size
-        self.seq_len = seq_len
-        self.queue: deque[Request] = deque()
-
-    def put(self, req: Request):
-        self.queue.append(req)
-
-    def __len__(self) -> int:
-        return len(self.queue)
-
-    def next_batch(self) -> tuple[list[Request], np.ndarray] | None:
-        """Pop up to batch_size requests -> (requests, tokens [B_actual, S])."""
-        if not self.queue:
-            return None
-        reqs = [self.queue.popleft()
-                for _ in range(min(self.batch_size, len(self.queue)))]
-        return reqs, stack_tokens(reqs, self.seq_len)
 
 
 class ContinuousBatcher:

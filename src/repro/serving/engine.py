@@ -1,14 +1,10 @@
 """Pipeline serving engine: real JAX models behind each stage.
 
-StageServer = one task's deployment: a model variant (ArchConfig), a batch
-size, and a replica count (replicas are data-parallel splits of a batch; on
-the CPU dev box they execute sequentially but the abstraction mirrors the
-mesh "data"-axis replica groups of the production launch).
-
-PipelineServer chains stages (the paper's gRPC hops) and implements
-``apply_config`` — the Kubernetes-API reconfiguration the OPD agent calls:
-switching a stage's variant swaps model params (a re-shard/cold-start in
-production, charged by the simulator).
+StageServer = one task's model variants (ArchConfigs) behind one callable
+that runs a chosen variant on a batch of tokens. It holds no queue and no
+configuration: the event-driven runtime (`serving.runtime`) queues and
+batches requests, picks each stage's variant, batch size and replicas, and
+attaches the server as the stage's ``executor``.
 """
 from __future__ import annotations
 
@@ -19,10 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.core.mdp import Config
 from repro.models import api
 from repro.models.config import ArchConfig
-from repro.serving.batcher import Batcher, Request
 
 
 class StageServer:
@@ -40,26 +34,22 @@ class StageServer:
     """
 
     def __init__(self, name: str, variants: list[ArchConfig], *,
-                 seq_len: int = 32, batch_size: int = 4, replicas: int = 1,
-                 seed: int = 0):
+                 seq_len: int = 32, seed: int = 0):
         self.name = name
         self.variants = variants
         self.seq_len = seq_len
         self.seed = seed
-        self.z = 0
-        self.replicas = replicas
-        self.batcher = Batcher(batch_size, seq_len)
         self.params: dict[int, object] = {}       # z -> weights, on demand
         # (z, B) -> (executable, stub inputs)
         self._compiled: dict[tuple[int, int], tuple[object, dict]] = {}
         self.compile_s = 0.0
         self.stub_inputs = {"drawn": 0, "reused": 0}  # stage calls, by path
         self.batches: dict[int, int] = {}         # z -> batches executed
-        self.served = 0
 
     @property
     def cfg(self) -> ArchConfig:
-        return self.variants[self.z]
+        """The first variant, the one ``z = 0`` runs."""
+        return self.variants[0]
 
     def weights(self, z: int):
         """Variant ``z``'s weights, created by one jitted program on first
@@ -88,15 +78,6 @@ class StageServer:
             stubs = {k: v for k, v in batch.items() if k != "tokens"}
             self._compiled[key] = (exe, stubs)
         return self._compiled[key][0]
-
-    def configure(self, *, z: int | None = None, batch_size: int | None = None,
-                  replicas: int | None = None):
-        if z is not None:
-            self.z = int(z) % len(self.variants)
-        if batch_size is not None:
-            self.batcher.batch_size = int(batch_size)
-        if replicas is not None:
-            self.replicas = int(replicas)
 
     @staticmethod
     def _host_tokens(tokens: np.ndarray, cfg: ArchConfig) -> np.ndarray:
@@ -163,49 +144,3 @@ class StageServer:
                 "compiled_shapes": len(self._compiled),
                 "compile_s": self.compile_s,
                 "stub_inputs": dict(self.stub_inputs)}
-
-    def serve_pending(self) -> list[Request]:
-        """Drain the queue; returns completed requests with stage output."""
-        done = []
-        while True:
-            nb = self.batcher.next_batch()
-            if nb is None:
-                return done
-            reqs, toks = nb
-            # replicas split the batch (data parallel); sequential on CPU
-            out = self.execute(self.z, toks)
-            for i, req in enumerate(reqs):
-                req.stage_outputs.append(out[i])
-                req.result = out[i]
-                done.append(req)
-            self.served += len(reqs)
-
-
-class PipelineServer:
-    def __init__(self, stages: list[StageServer]):
-        self.stages = stages
-        self.completed: list[Request] = []
-        self.switch_count = 0
-
-    def apply_config(self, cfg: Config, batch_choices: list[int] | None = None):
-        """The OPD action -> live reconfiguration (paper: K8s Python API)."""
-        for n, stage in enumerate(self.stages):
-            if stage.z != cfg.z[n] % len(stage.variants):
-                self.switch_count += 1
-            stage.configure(z=cfg.z[n], batch_size=cfg.b[n], replicas=cfg.f[n])
-
-    def submit(self, req: Request):
-        self.stages[0].batcher.put(req)
-
-    def process(self) -> list[Request]:
-        """Push every queued request through all stages (gRPC chain)."""
-        for i, stage in enumerate(self.stages):
-            finished = stage.serve_pending()
-            if i + 1 < len(self.stages):
-                for req in finished:
-                    # next stage consumes this stage's output tokens
-                    req.tokens = np.asarray(req.result, dtype=np.int32)
-                    self.stages[i + 1].batcher.put(req)
-            else:
-                self.completed.extend(finished)
-        return self.completed

@@ -200,7 +200,6 @@ class FleetRuntime:
         return {
             "fleet": {
                 "tenants": len(self.tenants),
-                "virtual_time_s": self.loop.now,
                 "events": self.loop.events,
                 "offered": offered,
                 "served": served,

@@ -33,14 +33,10 @@ import math
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.core.mdp import Config, Pipeline, Task, placement_for
+from repro.core.mdp import (COLD_START_SECONDS, DEFAULT_MAX_WAIT, Config,
+                            Pipeline, Task, placement_for)
 from repro.serving.batcher import ContinuousBatcher, Request, stack_tokens
 from repro.serving.telemetry import Telemetry
-
-# Virtual-time cost of a variant switch: the paper's cold start loses
-# COLD_START_FRACTION (0.3) of a 10 s adaptation interval's capacity.
-COLD_START_SECONDS = 3.0
-DEFAULT_MAX_WAIT = 0.25   # s a request may wait before a partial batch fires
 
 
 class EventLoop:

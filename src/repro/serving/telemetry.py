@@ -7,8 +7,7 @@ Interval queries (``completed_in`` / ``arrived_in`` / ``latencies``) are
 O(log n + window): the event loop records completions in non-decreasing
 finish time and arrivals in non-decreasing arrival time, so both live in
 sorted parallel arrays sliced with ``bisect`` (an out-of-order record falls
-back to an insort, keeping the invariant). ``benchmarks/telemetry_queries.py``
-asserts per-query cost stays flat as the record count grows.
+back to an insort, keeping the invariant).
 """
 from __future__ import annotations
 

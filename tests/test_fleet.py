@@ -244,3 +244,42 @@ class TestFleetSessionShape:
         assert s["fleet"]["offered"] == sum(
             t["arrived"] for t in s["tenants"].values()
         )
+
+
+# Each tenant's virtual-time tail and shed count over 60 s, recorded from the
+# fleet before its CPU speed benchmark was retired: "registered" serves the
+# fleet as registered, "overloaded" at 400/300/250 req/s under an admission
+# limit of 3000 (that benchmark's load, about 1e5 requests over 120 s).
+OVERLOAD_RATES = {"interactive": 400.0, "analytics": 300.0, "batch": 250.0}
+RECORDED_TAILS = {
+    "registered": {
+        "interactive": {"served": 1347, "shed": 0, "p99": 3.131542181093147},
+        "analytics": {"served": 897, "shed": 0, "p99": 3.7606152261006485},
+        "batch": {"served": 1599, "shed": 26, "p99": 1.6443374112516966},
+    },
+    "overloaded": {
+        "interactive": {"served": 21316, "shed": 0, "p99": 3.138853665491759},
+        "analytics": {"served": 17318, "shed": 751, "p99": 3.7062383204866527},
+        "batch": {"served": 15442, "shed": 4152, "p99": 1.0365989816756063},
+    },
+}
+
+
+@pytest.mark.parametrize("load", sorted(RECORDED_TAILS))
+def test_tenant_tails_and_shed_match_the_record(load):
+    spec = _fleet_spec()
+    if load == "overloaded":
+        tenants = tuple(
+            api.replace(
+                t, scenario=api.replace(t.scenario, rate=OVERLOAD_RATES[t.name])
+            )
+            for t in spec.tenants
+        )
+        spec = api.replace(spec, tenants=tenants, admission_limit=3000.0)
+    rep = api.FleetSession.from_spec(spec).serve(horizon=60)
+    got = rep["summary"]["tenants"]
+    assert set(got) == set(RECORDED_TAILS[load])
+    for name, want in RECORDED_TAILS[load].items():
+        assert got[name]["served"] == want["served"], name
+        assert got[name]["shed"] == want["shed"], name
+        assert got[name]["p99"] == pytest.approx(want["p99"], rel=1e-9), name

@@ -1,4 +1,5 @@
-"""Serving engine integration: pipeline chaining, batching, reconfiguration."""
+"""Serving engine integration: live stage executors behind the runtime,
+batching, reconfiguration."""
 import gc
 import weakref
 
@@ -7,68 +8,95 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.cluster.perf_model import make_pipeline
 from repro.configs import ARCHS
-from repro.core.mdp import Config
+from repro.core.mdp import COLD_START_SECONDS, Config
 from repro.data import synthetic_lm_batches, synthetic_requests
 from repro.models import api as models
-from repro.serving import PipelineServer, StageServer
+from repro.serving import (ContinuousBatcher, Request, ServingRuntime,
+                           StageServer, stack_tokens)
+
+
+VARIANTS = (("xlstm-125m", "whisper-small"),
+            ("llama3.2-1b", "granite-moe-3b-a800m"))
 
 
 @pytest.fixture(scope="module")
-def server():
-    stages = [
-        StageServer(
-            "s0",
-            [ARCHS["xlstm-125m"].smoke(), ARCHS["whisper-small"].smoke()],
-            seed=0,
-        ),
-        StageServer(
-            "s1",
-            [ARCHS["llama3.2-1b"].smoke(), ARCHS["granite-moe-3b-a800m"].smoke()],
-            seed=1,
-        ),
-    ]
-    return PipelineServer(stages)
+def servers():
+    """Live smoke-width models behind each stage, shared by the runtime
+    tests below so each (variant, batch size) compiles once."""
+    return [StageServer(f"s{i}", [ARCHS[n].smoke() for n in names], seed=i)
+            for i, names in enumerate(VARIANTS)]
 
 
-def test_requests_flow_through_all_stages(server):
-    n0 = len(server.completed)
+def _runtime(servers, cfg):
+    """The event-driven runtime over ``servers``: its pipeline lists the
+    same variants in the same order, so ``z`` picks the same model."""
+    pipe = make_pipeline([[ARCHS[n] for n in names] for names in VARIANTS],
+                         quants=("bf16",))
+    return ServingRuntime.from_pipeline(pipe, cfg=cfg, executors=servers)
+
+
+def _executed(server, z):
+    """Batches ``server`` has run on variant ``z``."""
+    name = server.variants[z].name
+    return server.stats()["executed"].get(name, {}).get("batches", 0)
+
+
+def test_requests_flow_through_all_stages(servers):
+    rt = _runtime(servers, Config(z=(0, 0), f=(1, 1), b=(4, 4)))
     for r in synthetic_requests(7, vocab=256, seq_len=32, seed=0):
-        server.submit(r)
-    done = server.process()
-    new = done[n0:]
-    assert len(new) == 7
-    for req in new:
+        rt.submit(r, at=0.0)
+    rt.drain()
+    assert len(rt.completed) == 7
+    assert rt.in_system == 0
+    for req in rt.completed:
         assert len(req.stage_outputs) == 2
         assert req.result.shape == (32,)
 
 
-def test_reconfigure_switches_variant(server):
-    server.apply_config(Config(z=(1, 0), f=(2, 1), b=(2, 8)))
-    assert server.stages[0].z == 1
-    assert server.stages[0].batcher.batch_size == 2
-    assert server.stages[1].batcher.batch_size == 8
-    assert server.switch_count >= 1
+def test_reconfigure_switches_variant(servers):
+    rt = _runtime(servers, Config(z=(0, 0), f=(1, 1), b=(4, 4)))
     for r in synthetic_requests(3, vocab=256, seq_len=32, seed=1):
-        server.submit(r)
-    before = len(server.completed)
-    server.process()
-    assert len(server.completed) - before == 3
+        rt.submit(r, at=0.0)
+    rt.run_until(1.0)
+    before = [_executed(servers[0], 1), _executed(servers[1], 0)]
+    switched_at = rt.now
+    assert rt.apply_config(Config(z=(1, 0), f=(2, 1), b=(2, 8))) == 1
+    assert rt.stages[0].z == 1
+    assert rt.stages[0].batcher.batch_size == 2
+    assert rt.stages[1].batcher.batch_size == 8
+    assert rt.switch_count == 1
+    for r in synthetic_requests(3, vocab=256, seq_len=32, seed=2):
+        r.rid += 3
+        rt.submit(r)
+    rt.drain()
+    assert len(rt.completed) == 6
+    # after the cold start stage 0 runs the new variant, two at a time
+    after = [b for b in rt.telemetry.batches
+             if b.stage == 0 and b.time > switched_at]
+    assert len(after) == 2
+    assert all(b.time >= switched_at + COLD_START_SECONDS for b in after)
+    assert [b.size for b in after] == [2, 1]
+    assert _executed(servers[0], 1) - before[0] == 2
+    assert _executed(servers[1], 0) > before[1]
 
 
 def test_batcher_dispatches_actual_size():
     """Tail batches dispatch at their real size — no padded phantom rows."""
-    from repro.serving.batcher import Batcher, Request
-    b = Batcher(4, 8)
-    b.put(Request(rid=0, tokens=np.arange(8, dtype=np.int32)))
-    reqs, toks = b.next_batch()
+    b = ContinuousBatcher(4, max_wait=0.5)
+    b.put(Request(rid=0, tokens=np.arange(8, dtype=np.int32)), now=0.0)
+    reqs = b.pop(0.5)
+    toks = stack_tokens(reqs, 8)
     assert len(reqs) == 1
     assert toks.shape == (1, 8)              # actual batch, not batch_size
     assert (toks[0] == np.arange(8)).all()
     # short prompts zero-pad the sequence dimension only
-    b.put(Request(rid=1, tokens=np.arange(3, dtype=np.int32)))
-    b.put(Request(rid=2, tokens=np.arange(8, dtype=np.int32)))
-    reqs, toks = b.next_batch()
+    b.put(Request(rid=1, tokens=np.arange(3, dtype=np.int32)), now=1.0)
+    b.put(Request(rid=2, tokens=np.arange(8, dtype=np.int32)), now=1.0)
+    reqs = b.pop(1.5)
+    toks = stack_tokens(reqs, 8)
+    assert [r.rid for r in reqs] == [1, 2]
     assert toks.shape == (2, 8)
     assert (toks[0, 3:] == 0).all()
 
